@@ -36,6 +36,7 @@ import numpy as np
 
 from repro.core import LockSpec, Session, engine
 from repro.core.engine import FaultPlan
+from repro.launch.compile_cache import use_compile_cache
 
 RESULTS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "results", "bench")
@@ -108,6 +109,7 @@ def main(argv=None):
     ap.add_argument("--out", default=os.path.join(RESULTS,
                                                   "BENCH_faults.json"))
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     payload = bench_faults(quick=args.quick)
     for row in payload["rows"]:

@@ -18,6 +18,8 @@ import argparse
 import csv
 import os
 
+from repro.launch.compile_cache import use_compile_cache
+
 RESULTS = os.path.abspath(
     os.path.join(os.path.dirname(__file__), "..", "results", "bench"))
 
@@ -107,6 +109,7 @@ def main(argv=None):
                          "devices with XLA_FLAGS="
                          "--xla_force_host_platform_device_count=N)")
     args = ap.parse_args(argv)
+    use_compile_cache()
     os.makedirs(RESULTS, exist_ok=True)
 
     if args.tune:

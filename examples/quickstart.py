@@ -2,6 +2,7 @@
 
     PYTHONPATH=src python examples/quickstart.py
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -40,7 +41,12 @@ print(f"foMPI-RW: throughput={float(mbase.throughput):.3g}/s "
       f"RMA-RW)")
 
 # --- 2. The distributed hashtable case study (paper §5.3), TPU-style --
-dht = BatchedDHT(nb=8, TB=128, heap=1024)
+# The probe kernel compiles for the TPU; anywhere else it runs in the
+# Pallas interpreter.
+interpret = jax.default_backend() != "tpu"
+print(f"DHT kernel: interpret={interpret} "
+      f"(backend {jax.default_backend()})")
+dht = BatchedDHT(nb=8, TB=128, heap=1024, interpret=interpret)
 st = dht.init()
 keys = jnp.asarray(np.random.RandomState(0).permutation(10_000)[:200] + 1,
                    jnp.int32)

@@ -32,8 +32,13 @@ def main():
     store = VersionedStore(params, n_workers=BATCH, T_DC=4)
     decode = jax.jit(build_decode_step(cfg))
 
-    # Request-metadata DHT: request_id -> batch slot.
-    dht = BatchedDHT(nb=4, TB=64, heap=256)
+    # Request-metadata DHT: request_id -> batch slot. The probe kernel
+    # compiles for the TPU; anywhere else it runs in the Pallas
+    # interpreter.
+    interpret = jax.default_backend() != "tpu"
+    print(f"DHT kernel: interpret={interpret} "
+          f"(backend {jax.default_backend()})")
+    dht = BatchedDHT(nb=4, TB=64, heap=256, interpret=interpret)
     meta = dht.init()
     req_ids = jnp.asarray(np.random.RandomState(0)
                           .permutation(10_000)[:BATCH] + 1, jnp.int32)

@@ -651,6 +651,23 @@ def _run(handlers, max_events: int, st: SimState, seed) -> SimState:
     return _run_jit(handlers, max_events, st, seed)
 
 
+def pairwise_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """Sum of a float vector in one fixed pairwise order.
+
+    XLA picks the order of a `jnp.sum` itself, and on the TPU that order
+    changes with the batch shape around the reduction. Elementwise adds
+    are never reordered, so halving the vector until one element is left
+    gives the same bits whether a run is dispatched alone, in a grid, or
+    sharded over devices.
+    """
+    n = 1 << max(x.shape[0] - 1, 0).bit_length()
+    x = jnp.pad(x, (0, n - x.shape[0]))
+    while n > 1:
+        n //= 2
+        x = x[:n] + x[n:]
+    return x[0]
+
+
 def summarize(st: SimState) -> Metrics:
     """Reduce a final SimState to Metrics (traceable; vmap for batches).
 
@@ -667,7 +684,7 @@ def summarize(st: SimState) -> Metrics:
         violations=st.violations,
         makespan=mk,
         total_acquires=total,
-        mean_latency=jnp.sum(st.lat_sum) / jnp.maximum(total, 1),
+        mean_latency=pairwise_sum(st.lat_sum) / jnp.maximum(total, 1),
         throughput=total.astype(jnp.float32) / (mk * 1e-6),
         events=st.events,
         locality=st.local_passes / jnp.maximum(st.total_passes, 1),

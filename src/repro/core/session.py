@@ -29,12 +29,12 @@ Multi-device sharding: every execution shape takes a `devices=` knob
 (constructor default + per-call override). With devices given, the
 flattened (lattice points × seeds) batch is padded to a device
 multiple with dead entries, sharded over a 1D mesh
-(`launch.mesh.make_batch_mesh`) via `shard_map` (pmap on very old
-jax), and the Metrics are unpadded back — per-entry results are
-bitwise-equal to the single-device dispatch because entries never
-interact (the vmapped `lax.while_loop` keeps each lane's trajectory
-independent). `devices=None` (the default) keeps the classic
-single-device dispatch.
+(`launch.mesh.make_batch_mesh`) via `jax.shard_map`, and the Metrics
+are unpadded back — per-entry results are bitwise-equal to the
+single-device dispatch because entries never interact (the vmapped
+`lax.while_loop` keeps each lane's trajectory independent, and
+`engine.pairwise_sum` fixes the one float reduction's order).
+`devices=None` (the default) keeps the classic single-device dispatch.
 
 Seed-level caching: the jitted program is cached per (handlers,
 max_events) by JAX, and handlers are cached per environment by the
@@ -54,16 +54,6 @@ from repro.core import engine
 from repro.core.spec import EXTRA_WORDS, LockSpec
 from repro.core.topology import counter_ranks
 from repro.core.window import build_layout
-
-# shard_map moved out of jax.experimental over jax's lifetime; prefer
-# the public name, fall back to experimental, else pmap (see
-# `Session._build_shard_fn`).
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    try:
-        from jax.experimental.shard_map import shard_map as _shard_map
-    except ImportError:                           # pragma: no cover
-        _shard_map = None
 
 # Sentinel for "devices not passed": per-call `devices=None` forces the
 # single-device path even on a Session constructed with devices.
@@ -353,34 +343,12 @@ class Session:
             return jax.vmap(functools.partial(
                 self._point_entry, dyn, st0))(idx, seeds)
 
-        if _shard_map is not None:
-            import inspect
-
-            P = jax.sharding.PartitionSpec
-            # Disable the replication check: jax<0.5 has no replication
-            # rule for while_loop, and every output is explicitly
-            # batch-sharded so it adds nothing. The kwarg was renamed
-            # check_rep -> check_vma when shard_map went public.
-            params = inspect.signature(_shard_map).parameters
-            check = {k: False for k in ("check_rep", "check_vma")
-                     if k in params}
-            return jax.jit(_shard_map(
-                tile, mesh=mesh,
-                in_specs=(P(), P(), P("batch"), P("batch")),
-                out_specs=P("batch"), **check))
-
-        # pmap fallback (no shard_map in this jax): same tile body over
-        # explicit [D, B/D] chunks; dyn/st0 broadcast to every device.
-        D = len(devices)
-        pfn = jax.pmap(tile, in_axes=(None, None, 0, 0),
-                       devices=list(devices))
-
-        def run(dyn, st0, idx, seeds):
-            m = pfn(dyn, st0, idx.reshape(D, -1), seeds.reshape(D, -1))
-            return engine.Metrics(
-                *(leaf.reshape((-1,) + leaf.shape[2:]) for leaf in m))
-
-        return run
+        P = jax.sharding.PartitionSpec
+        # Every output is explicitly batch-sharded, so the varying-axes
+        # check adds nothing.
+        return jax.jit(jax.shard_map(
+            tile, mesh=mesh, in_specs=(P(), P(), P("batch"), P("batch")),
+            out_specs=P("batch"), check_vma=False))
 
     def _build_sweep_fn(self):
         program, env, max_events = self.program, self.env, self.max_events

@@ -28,7 +28,7 @@ class DHTState(NamedTuple):
 
 class BatchedDHT:
     def __init__(self, nb: int = 16, TB: int = 256, heap: int = 4096,
-                 interpret: bool | None = None):
+                 interpret: bool = False):
         self.nb, self.TB, self.heap = nb, TB, heap
         self.interpret = interpret
 

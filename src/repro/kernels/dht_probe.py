@@ -28,72 +28,81 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# JAX renamed TPUCompilerParams -> CompilerParams; support both.
-try:
-    CompilerParams = pltpu.CompilerParams
-except AttributeError:
-    CompilerParams = pltpu.TPUCompilerParams
-
 EMPTY = -1
+
+
+def _flip(v, n):
+    """[1, n] <-> [n, 1] by a masked reduction over the n x n identity
+    (Mosaic has no relayout of a lane vector into sublanes)."""
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    axis = 1 if v.shape[0] == 1 else 0
+    return jnp.sum(jnp.where(eye, v, 0), axis=axis, keepdims=True)
+
+
+def _onehot(keys, KB, TB):
+    """Keys as a [KB, 1] column -> (valid [KB, 1], one-hot [KB, TB])."""
+    valid = keys != EMPTY
+    slot = jnp.where(valid, keys % TB, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (KB, TB), 1)
+    return valid, (slot == lane) & valid
 
 
 def _insert_kernel(tk_ref, tv_ref, keys_ref, vals_ref,
                    tk_out, tv_out, status_out, *, KB, TB):
-    tk = tk_ref[0, :]                                  # [TB]
-    tv = tv_ref[0, :]
-    keys = keys_ref[0, :]                              # [KB]
-    vals = vals_ref[0, :]
-    valid = keys != EMPTY
-
-    slot = jnp.where(valid, keys % TB, 0)              # [KB]
-    iota_s = jax.lax.broadcasted_iota(jnp.int32, (KB, TB), 1)
-    onehot = (slot[:, None] == iota_s) & valid[:, None]   # [KB, TB]
+    tk = tk_ref[0]                                     # [1, TB]
+    tv = tv_ref[0]
+    keys = _flip(keys_ref[0], KB)                      # [KB, 1]
+    vals = _flip(vals_ref[0], KB)
+    valid, onehot = _onehot(keys, KB, TB)              # [KB, TB]
 
     # Incumbent key at each lane's slot (one-hot "gather").
-    inc_k = jnp.sum(jnp.where(onehot, tk[None, :], 0), axis=1)
-    occupied_i = jnp.sum(jnp.where(onehot, (tk != EMPTY)[None, :], False),
-                         axis=1) > 0
+    inc_k = jnp.sum(jnp.where(onehot, tk, 0), axis=1, keepdims=True)
+    occupied = jnp.sum(jnp.where(onehot & (tk != EMPTY), 1, 0), axis=1,
+                       keepdims=True) > 0
 
     # First arrival per slot: no earlier lane contends for my slot.
-    li = jax.lax.broadcasted_iota(jnp.int32, (KB, KB), 0)
-    lj = jax.lax.broadcasted_iota(jnp.int32, (KB, KB), 1)
-    same = (slot[:, None] == slot[None, :]) & valid[:, None] & valid[None, :]
-    earlier = jnp.sum(jnp.where(same & (lj < li), 1, 0), axis=1) > 0
+    lane_i = jax.lax.broadcasted_iota(jnp.int32, (KB, TB), 0)
+    first = jnp.min(jnp.where(onehot, lane_i, KB), axis=0, keepdims=True)
+    first_i = jnp.sum(jnp.where(onehot, first, 0), axis=1, keepdims=True)
+    earlier = first_i < jax.lax.broadcasted_iota(jnp.int32, (KB, 1), 0)
 
-    update = valid & occupied_i & (inc_k == keys)
-    insert = valid & ~occupied_i & ~earlier
+    update = valid & occupied & (inc_k == keys)
+    insert = valid & ~occupied & ~earlier
     status = jnp.where(~valid, 3,
                        jnp.where(insert, 0,
                                  jnp.where(update, 1, 2)))
 
     # Claims: winners' one-hot columns fold into the table (no scatter).
-    win_oh = onehot & insert[:, None]                  # [KB, TB]
-    claimed = jnp.sum(win_oh, axis=0) > 0              # [TB]
-    claim_k = jnp.sum(jnp.where(win_oh, keys[:, None], 0), axis=0)
-    claim_v = jnp.sum(jnp.where(win_oh, vals[:, None], 0), axis=0)
-    upd_oh = onehot & update[:, None]
-    updated = jnp.sum(upd_oh, axis=0) > 0
-    upd_v = jnp.sum(jnp.where(upd_oh, vals[:, None], 0), axis=0)
+    win_oh = onehot & insert                           # [KB, TB]
+    claimed = jnp.sum(jnp.where(win_oh, 1, 0), axis=0, keepdims=True) > 0
+    claim_k = jnp.sum(jnp.where(win_oh, keys, 0), axis=0, keepdims=True)
+    claim_v = jnp.sum(jnp.where(win_oh, vals, 0), axis=0, keepdims=True)
+    upd_oh = onehot & update
+    updated = jnp.sum(jnp.where(upd_oh, 1, 0), axis=0, keepdims=True) > 0
+    upd_v = jnp.sum(jnp.where(upd_oh, vals, 0), axis=0, keepdims=True)
 
-    tk_out[0, :] = jnp.where(claimed, claim_k, tk)
-    tv_out[0, :] = jnp.where(claimed, claim_v,
-                             jnp.where(updated, upd_v, tv))
-    status_out[0, :] = status
+    tk_out[0] = jnp.where(claimed, claim_k, tk)
+    tv_out[0] = jnp.where(claimed, claim_v, jnp.where(updated, upd_v, tv))
+    status_out[0] = _flip(status, KB)
 
 
 def _lookup_kernel(tk_ref, tv_ref, keys_ref, val_out, hit_out, *, KB, TB):
-    tk = tk_ref[0, :]
-    tv = tv_ref[0, :]
-    keys = keys_ref[0, :]
-    valid = keys != EMPTY
-    slot = jnp.where(valid, keys % TB, 0)
-    iota_s = jax.lax.broadcasted_iota(jnp.int32, (KB, TB), 1)
-    onehot = (slot[:, None] == iota_s) & valid[:, None]
-    inc_k = jnp.sum(jnp.where(onehot, tk[None, :], 0), axis=1)
-    inc_v = jnp.sum(jnp.where(onehot, tv[None, :], 0), axis=1)
+    tk = tk_ref[0]
+    tv = tv_ref[0]
+    keys = _flip(keys_ref[0], KB)
+    valid, onehot = _onehot(keys, KB, TB)
+    inc_k = jnp.sum(jnp.where(onehot, tk, 0), axis=1, keepdims=True)
+    inc_v = jnp.sum(jnp.where(onehot, tv, 0), axis=1, keepdims=True)
     hit = valid & (inc_k == keys)
-    val_out[0, :] = jnp.where(hit, inc_v, EMPTY)
-    hit_out[0, :] = hit
+    val_out[0] = _flip(jnp.where(hit, inc_v, EMPTY), KB)
+    hit_out[0] = _flip(jnp.where(hit, 1, 0), KB)
+
+
+def _rows(n):
+    """BlockSpec of one [1, n] row of an [nb, 1, n] array: the block's
+    last two dims equal the array's, as the TPU tiling requires."""
+    return pl.BlockSpec((1, 1, n), lambda b: (b, 0, 0))
 
 
 def dht_insert(table_keys, table_vals, keys, vals, *, interpret=False):
@@ -103,41 +112,35 @@ def dht_insert(table_keys, table_vals, keys, vals, *, interpret=False):
     nb, TB = table_keys.shape
     KB = keys.shape[1]
     kernel = functools.partial(_insert_kernel, KB=KB, TB=TB)
-    return pl.pallas_call(
+    tk, tv, status = pl.pallas_call(
         kernel,
         grid=(nb,),
-        in_specs=[pl.BlockSpec((1, TB), lambda b: (b, 0)),
-                  pl.BlockSpec((1, TB), lambda b: (b, 0)),
-                  pl.BlockSpec((1, KB), lambda b: (b, 0)),
-                  pl.BlockSpec((1, KB), lambda b: (b, 0))],
-        out_specs=[pl.BlockSpec((1, TB), lambda b: (b, 0)),
-                   pl.BlockSpec((1, TB), lambda b: (b, 0)),
-                   pl.BlockSpec((1, KB), lambda b: (b, 0))],
-        out_shape=[jax.ShapeDtypeStruct((nb, TB), jnp.int32),
-                   jax.ShapeDtypeStruct((nb, TB), jnp.int32),
-                   jax.ShapeDtypeStruct((nb, KB), jnp.int32)],
-        compiler_params=CompilerParams(
+        in_specs=[_rows(TB), _rows(TB), _rows(KB), _rows(KB)],
+        out_specs=[_rows(TB), _rows(TB), _rows(KB)],
+        out_shape=[jax.ShapeDtypeStruct((nb, 1, TB), jnp.int32),
+                   jax.ShapeDtypeStruct((nb, 1, TB), jnp.int32),
+                   jax.ShapeDtypeStruct((nb, 1, KB), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(table_keys, table_vals, keys, vals)
+    )(*(x[:, None, :] for x in (table_keys, table_vals, keys, vals)))
+    return tk[:, 0], tv[:, 0], status[:, 0]
 
 
 def dht_lookup(table_keys, table_vals, keys, *, interpret=False):
-    """Blocked lookup. Returns (vals [nb, KB], hit [nb, KB])."""
+    """Blocked lookup. Returns (vals [nb, KB], hit [nb, KB] bool)."""
     nb, TB = table_keys.shape
     KB = keys.shape[1]
     kernel = functools.partial(_lookup_kernel, KB=KB, TB=TB)
-    return pl.pallas_call(
+    vals, hit = pl.pallas_call(
         kernel,
         grid=(nb,),
-        in_specs=[pl.BlockSpec((1, TB), lambda b: (b, 0)),
-                  pl.BlockSpec((1, TB), lambda b: (b, 0)),
-                  pl.BlockSpec((1, KB), lambda b: (b, 0))],
-        out_specs=[pl.BlockSpec((1, KB), lambda b: (b, 0)),
-                   pl.BlockSpec((1, KB), lambda b: (b, 0))],
-        out_shape=[jax.ShapeDtypeStruct((nb, KB), jnp.int32),
-                   jax.ShapeDtypeStruct((nb, KB), jnp.bool_)],
-        compiler_params=CompilerParams(
+        in_specs=[_rows(TB), _rows(TB), _rows(KB)],
+        out_specs=[_rows(KB), _rows(KB)],
+        out_shape=[jax.ShapeDtypeStruct((nb, 1, KB), jnp.int32),
+                   jax.ShapeDtypeStruct((nb, 1, KB), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(table_keys, table_vals, keys)
+    )(*(x[:, None, :] for x in (table_keys, table_vals, keys)))
+    return vals[:, 0], hit[:, 0] != 0

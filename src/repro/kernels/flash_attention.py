@@ -21,12 +21,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# JAX renamed TPUCompilerParams -> CompilerParams; support both.
-try:
-    CompilerParams = pltpu.CompilerParams
-except AttributeError:
-    CompilerParams = pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -109,7 +103,7 @@ def flash_attention(q, k, v, *, causal=True, window=None,
             pltpu.VMEM((block_q,), jnp.float32),      # l
             pltpu.VMEM((block_q, dh), jnp.float32),   # acc
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -1,13 +1,12 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels run with interpret=True; on a real
-TPU set REPRO_PALLAS_INTERPRET=0 (or pass interpret=False) to compile
-them to Mosaic.
+The kernels compile for the TPU (Mosaic). `interpret=True` runs their
+bodies through the Pallas interpreter instead, which is how they run on
+a CPU; the caller always chooses.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -17,26 +16,17 @@ from repro.kernels import dht_probe, flash_attention as fa, ssd_scan as ssd
 EMPTY = jnp.int32(-1)
 
 
-def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
                                              "block_kv", "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=None, block_q=128,
-                    block_kv=128, interpret=None):
-    interpret = _interpret_default() if interpret is None else interpret
+                    block_kv=128, interpret=False):
     return fa.flash_attention(q, k, v, causal=causal, window=window,
                               block_q=block_q, block_kv=block_kv,
                               interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, A, B, C, *, chunk=128, interpret=None):
-    interpret = _interpret_default() if interpret is None else interpret
+def ssd_scan(x, dt, A, B, C, *, chunk=128, interpret=False):
     return ssd.ssd_scan(x, dt, A, B, C, chunk=chunk, interpret=interpret)
 
 
@@ -63,14 +53,13 @@ def route_keys(keys, vals, nb: int, TB: int, KB: int):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def dht_insert(table_keys, table_vals, keys, vals, *, interpret=None):
+def dht_insert(table_keys, table_vals, keys, vals, *, interpret=False):
     """Insert a key batch into the blocked table.
 
     table_*: [nb, TB]; keys/vals: [K] (distinct keys). Returns
     (table_keys', table_vals', status [K]) with status 0=insert,
     1=update, 2=overflow (incl. bucket-capacity overflow).
     """
-    interpret = _interpret_default() if interpret is None else interpret
     nb, TB = table_keys.shape
     KB = min(max(int(keys.shape[0]), 8), 512)
     keys_r, vals_r, idx = route_keys(keys, vals, nb, TB, KB)
@@ -83,8 +72,7 @@ def dht_insert(table_keys, table_vals, keys, vals, *, interpret=None):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def dht_lookup(table_keys, table_vals, keys, *, interpret=None):
-    interpret = _interpret_default() if interpret is None else interpret
+def dht_lookup(table_keys, table_vals, keys, *, interpret=False):
     nb, TB = table_keys.shape
     KB = min(max(int(keys.shape[0]), 8), 512)
     keys_r, _, idx = route_keys(keys, keys, nb, TB, KB)

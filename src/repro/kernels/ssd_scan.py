@@ -23,12 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# JAX renamed TPUCompilerParams -> CompilerParams; support both.
-try:
-    CompilerParams = pltpu.CompilerParams
-except AttributeError:
-    CompilerParams = pltpu.TPUCompilerParams
-
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, s_ref, state_ref, *,
             chunk):
@@ -104,7 +98,7 @@ def ssd_scan(x, dt, A, B, C, *, chunk=128, interpret=False):
             jax.ShapeDtypeStruct((b, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, A, B, C)

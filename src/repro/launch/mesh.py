@@ -15,16 +15,27 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape, axes):
+    """Mesh whose axes are all `Auto`: the model substrate places arrays
+    with `NamedSharding` and `with_sharding_constraint` and lets the
+    compiler propagate the rest. `jax.make_mesh` defaults to `Explicit`
+    axes, under which every gather and reshape of a sharded array must
+    name its output sharding."""
+    from jax.sharding import AxisType
+
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many devices the host actually has
     (tests / examples)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_batch_mesh(devices=None):

@@ -35,7 +35,8 @@ def test_elastic_restore_other_mesh(tmp_path):
         from repro.train.step import build_train_step, init_state
         from repro.data import batch_for
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(data=2, model=4)
         state = jax.eval_shape(lambda k: init_state(TINY, k),
                                jax.random.PRNGKey(0))
         pspecs = shd.param_spec_tree(state.params, mesh)

@@ -137,6 +137,28 @@ def test_makespan_monotone_in_events():
     assert m4 > m2
 
 
+@pytest.mark.parametrize("n", [1, 48, 1024])
+def test_pairwise_sum_is_one_fixed_order(n):
+    """mean_latency's sum over processes follows one pairwise order, the
+    same bits alone and inside batches of any size, so grid, run_batch
+    and sharded dispatches agree per point on every backend."""
+    rng = np.random.default_rng(n)
+    x = rng.uniform(0, 1e4, size=(8, n)).astype(np.float32)
+    ref = []
+    for row in x:
+        r = np.concatenate([row, np.zeros(-n % (1 << (n - 1).bit_length()),
+                                          np.float32)])
+        while r.size > 1:
+            r = r[:r.size // 2] + r[r.size // 2:]
+        ref.append(r[0])
+    ref = np.asarray(ref, np.float32)
+    for b in (1, 3, 8):
+        got = np.asarray(jax.jit(jax.vmap(engine.pairwise_sum))(x[:b]))
+        assert got.tobytes() == ref[:b].tobytes()
+    np.testing.assert_allclose(ref, x.astype(np.float64).sum(axis=1),
+                               rtol=1e-5)
+
+
 # ------------------------------------------------ tuner input hardening
 def test_spec_rejects_tdc_above_p():
     """T_DC > P silently degraded to one counter in counter_ranks;
