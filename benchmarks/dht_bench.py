@@ -4,15 +4,9 @@ P-1 processes hammer one victim volume with F_W inserts / (1-F_W)
 reads under three synchronization schemes: foMPI-A (lock-free
 CAS/FAO), foMPI-RW (centralized RW lock), RMA-RW (ours). Metric:
 total simulated execution time for a fixed op budget.
-
-Also includes a wall-clock micro-benchmark of the TPU batched table
-(the Pallas dht_probe path) vs its pure-jnp oracle.
 """
 from __future__ import annotations
 
-import time
-
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core import LockSpec, engine, writer_mask
@@ -74,29 +68,3 @@ def bench_dht(ps=(16, 64), fws=(0.0, 0.02, 0.05, 0.20), target_acq=4):
                    "rma_rw_us": _run_locked("rma_rw", P, fw, target_acq)}
             out.append(rec)
     return out
-
-
-def bench_batched_table(n_keys=512, nb=16, TB=256, iters=20):
-    """Wall-clock of the Pallas-kernel table vs a python-loop oracle."""
-    from repro.dht import BatchedDHT
-
-    rng = np.random.RandomState(0)
-    keys = jnp.asarray(rng.permutation(1 << 20)[:n_keys] + 1, jnp.int32)
-    vals = jnp.arange(n_keys, dtype=jnp.int32)
-    dht = BatchedDHT(nb=nb, TB=TB, heap=4 * n_keys, interpret=True)
-    st = dht.init()
-    st, _ = dht.insert(st, keys, vals)       # warm compile
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        st2, _ = dht.insert(dht.init(), keys, vals)
-        st2.table_keys.block_until_ready()
-    kernel_s = (time.perf_counter() - t0) / iters
-
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        out, _ = dht.lookup(st, keys)
-        out.block_until_ready()
-    lookup_s = (time.perf_counter() - t0) / iters
-    return [{"bench": "dht_table", "n_keys": n_keys,
-             "insert_us_per_batch": kernel_s * 1e6,
-             "lookup_us_per_batch": lookup_s * 1e6}]

@@ -99,7 +99,7 @@ def main(argv=None):
                     help="larger P sweep (P up to 1024; slow)")
     ap.add_argument("--only", default=None,
                     help="comma list: lb,ecsb,sob,wcsb,warb,rw,tdc,tl,tr,"
-                         "dht,table,kernels,roofline,faults")
+                         "dht,roofline,faults")
     ap.add_argument("--tune", action="store_true",
                     help="run the 3D grid auto-tuner and write "
                          "results/bench/tuned_spec.json")
@@ -119,8 +119,7 @@ def main(argv=None):
         run_tuner(args)
         return
 
-    from benchmarks import (dht_bench, faults, kernels_bench, locks,
-                            roofline, thresholds)
+    from benchmarks import dht_bench, faults, locks, roofline, thresholds
 
     ps = (16, 64) if args.quick else (16, 64, 256)
     if args.full:
@@ -169,16 +168,6 @@ def main(argv=None):
         write_csv("dht", rows)
         show("DHT case study (Fig. 6; total us, lower=better)", rows,
              ["P", "F_W", "fompi_a_us", "fompi_rw_us", "rma_rw_us"])
-    if want("table"):
-        rows = dht_bench.bench_batched_table()
-        write_csv("dht_table", rows)
-        show("Batched TPU table (interpret-mode wall us)", rows,
-             ["n_keys", "insert_us_per_batch", "lookup_us_per_batch"])
-    if want("kernels"):
-        rows = kernels_bench.bench_kernels()
-        write_csv("kernels", rows)
-        show("Pallas kernels (interpret-mode wall us)", rows,
-             ["bench", "shape", "pallas_us", "ref_us"])
     if want("faults"):
         payload = faults.bench_faults(quick=args.quick)
         rows = payload["rows"]

@@ -43,17 +43,12 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.core import LockSpec, Session, engine, metrics_at  # noqa: E402
-from repro.core.programs import hier  # noqa: E402
+from repro.core import LockSpec, Session, engine, metrics_at, spans  # noqa: E402
 from repro.dht import BatchedDHT  # noqa: E402
 from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 
-COMPILE_EVENTS = (
-    "/jax/core/compile/jaxpr_trace_duration",
-    "/jax/core/compile/jaxpr_to_mlir_module_duration",
-    "/jax/core/compile/backend_compile_duration",
-)
-CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+# The program's counters of JAX's compile events (`repro.core.spans`).
+COMPILE_COUNTERS = ("jit.trace_s", "jit.lower_s", "jit.compile_s")
 
 # Phase c's lattice: the paper's counter spacings, leaf thresholds and
 # reader batches around its benchmark point (T_DC=16, T_L=64, T_R=1024).
@@ -76,22 +71,23 @@ def check(ok, what: str):
 
 
 class Timer:
-    """Times calls: compile seconds from JAX's compile events, wall
-    seconds on the host clock until the results are ready."""
+    """Times calls: compile seconds from the program's counters of JAX's
+    compile events, wall seconds on the host clock until the results are
+    ready. Counts from the Timer's creation."""
 
     def __init__(self):
-        self.compile_s = 0.0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
+        self._start = spans.counters()
 
-    def _duration(self, event, duration_secs, **_):
-        if event in COMPILE_EVENTS:
-            self.compile_s += duration_secs
+    def _since_start(self, name):
+        return spans.counters()[name] - self._start[name]
 
-    def _event(self, event, **_):
-        if event == CACHE_HIT_EVENT:
-            self.cache_hits += 1
+    @property
+    def compile_s(self) -> float:
+        return sum(self._since_start(n) for n in COMPILE_COUNTERS)
+
+    @property
+    def cache_hits(self) -> int:
+        return self._since_start("jit.cache_hits")
 
     def once(self, label, fn):
         c0, t0 = self.compile_s, time.perf_counter()
@@ -118,20 +114,11 @@ class Timer:
 
 
 def count_builds(fn):
-    """Run fn() counting traces of the hierarchical point program."""
-    builds = {"n": 0}
-    orig = hier.HierProgram._build
-
-    def counting(self, env):
-        builds["n"] += 1
-        return orig(self, env)
-
-    hier.HierProgram._build = counting
-    try:
-        out = fn()
-    finally:
-        hier.HierProgram._build = orig
-    return out, builds["n"]
+    """Run fn() counting builds of a point program's handlers: one per
+    trace of a grid."""
+    before = spans.counters()["program.builds"]
+    out = fn()
+    return out, spans.counters()["program.builds"] - before
 
 
 def differing_fields(got, want) -> list:

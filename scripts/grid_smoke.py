@@ -21,25 +21,15 @@ import argparse
 
 import numpy as np
 
-from repro.core import LockSpec, Session, TuneResult, tune
-from repro.core.programs import hier
+from repro.core import LockSpec, Session, TuneResult, spans, tune
 
 
 def count_builds(fn):
-    """Run fn() counting HierProgram._build invocations (= traces)."""
-    builds = {"n": 0}
-    orig = hier.HierProgram._build
-
-    def counting(self, env):
-        builds["n"] += 1
-        return orig(self, env)
-
-    hier.HierProgram._build = counting
-    try:
-        out = fn()
-    finally:
-        hier.HierProgram._build = orig
-    return out, builds["n"]
+    """Run fn() counting builds of the point program's handlers (one
+    per trace)."""
+    before = spans.counters()["program.builds"]
+    out = fn()
+    return out, spans.counters()["program.builds"] - before
 
 
 def assert_bitwise(got, want, ctx):

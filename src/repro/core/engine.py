@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import checkify
 
+from repro.core import spans
 from repro.core.cost import CostModel, DEFAULT_COST
 from repro.core.topology import Machine, proc_distance_matrix
 from repro.core.window import Layout, padded_level_table
@@ -568,25 +569,48 @@ def step_loop(handlers, max_events: int, st: SimState, seed) -> SimState:
 
     def body(carry):
         st, key = carry
-        key, sub = jax.random.split(key)
-        parked = st.crashed & (st.t_ready >= INF)
-        tr = jnp.where(st.done | parked, INF, st.t_ready)
-        p = jnp.argmin(tr).astype(jnp.int32)
-        now = tr[p]
-        # Fault injection: a live process whose crash time has come
-        # crashes INSTEAD of executing its instruction; a crashed one
-        # being scheduled is (by the t_ready protocol) due for revive.
-        fault = st.crashed[p] | (now >= st.crash_t[p])
-        st = jax.lax.cond(
-            fault,
-            lambda op: _fault_event(op[0], op[1], op[2]),
-            lambda op: jax.lax.switch(op[0].pc[op[1]], handlers,
-                                      op[1], op[2], sub, op[0]),
-            (st, p, now))
+        with jax.named_scope("sched"):
+            key, sub = jax.random.split(key)
+            parked = st.crashed & (st.t_ready >= INF)
+            tr = jnp.where(st.done | parked, INF, st.t_ready)
+            p = jnp.argmin(tr).astype(jnp.int32)
+            now = tr[p]
+            # Fault injection: a live process whose crash time has come
+            # crashes INSTEAD of executing its instruction; a crashed one
+            # being scheduled is (by the t_ready protocol) due for revive.
+            fault = st.crashed[p] | (now >= st.crash_t[p])
+            pc = st.pc[p]
+
+        def fault_event(op):
+            with jax.named_scope("fault"):
+                return _fault_event(op[0], op[1], op[2])
+
+        def instruction(op):
+            with jax.named_scope("handlers"):
+                return jax.lax.switch(pc, handlers, op[1], op[2], sub, op[0])
+
+        st = jax.lax.cond(fault, fault_event, instruction, (st, p, now))
         return st, key
 
     st, _ = jax.lax.while_loop(cond, body, (st, key0))
     return st
+
+
+def handler_table(handlers: Sequence[Callable], pc_names) -> tuple:
+    """The handler tuple a program's `_build` returns: handler `pc` runs
+    under the named scope `pc.<pc_names[pc]>`, which names its ops in
+    the compiled program. Counts one `program.builds`."""
+    spans.count("program.builds")
+    return tuple(_scoped(h, "pc." + name)
+                 for h, name in zip(handlers, pc_names, strict=True))
+
+
+def _scoped(handler, scope: str):
+    @functools.wraps(handler)
+    def run(*args):
+        with jax.named_scope(scope):
+            return handler(*args)
+    return run
 
 
 @functools.partial(jax.jit, static_argnames=("handlers", "max_events"))
@@ -648,6 +672,7 @@ def _call_checked(fn, *args):
 def _run(handlers, max_events: int, st: SimState, seed) -> SimState:
     if checks_enabled():
         return _call_checked(_checked_run(handlers, max_events), st, seed)
+    spans.note_dispatch(_run_jit, (handlers, max_events), (st, seed))
     return _run_jit(handlers, max_events, st, seed)
 
 
@@ -708,6 +733,7 @@ def _run_batch(handlers, max_events: int, st: SimState,
     if checks_enabled():
         return _call_checked(_checked_run_batch(handlers, max_events),
                              st, seeds)
+    spans.note_dispatch(_run_batch_jit, (handlers, max_events), (st, seeds))
     return _run_batch_jit(handlers, max_events, st, seeds)
 
 

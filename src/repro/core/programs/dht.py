@@ -22,11 +22,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.engine import (Env, SimState, finish_instr,
+from repro.core.engine import (Env, SimState, finish_instr, handler_table,
                                memoized_build, think_duration)
 from repro.core.programs.meta import SEG_SCRATCH, ProgramMeta
 
 A_OP, A_OVERFLOW, A_DONE, A_CHAIN = 0, 1, 2, 3
+PC_NAMES = ("A_OP", "A_OVERFLOW", "A_DONE", "A_CHAIN")
 
 # The paper's benchmark operates the table at a high load factor (random
 # keys into a fixed-size table), so roughly half of the accesses touch
@@ -75,7 +76,7 @@ class FompiADHT:
             dead.add(A_CHAIN)
         return ProgramMeta(
             name="fompi_a_dht", n_pcs=4, n_regs=self.n_regs,
-            pc_names=("A_OP", "A_OVERFLOW", "A_DONE", "A_CHAIN"),
+            pc_names=PC_NAMES,
             dead_pcs=frozenset(dead),
             cs_enter_pcs=frozenset(),
             cs_exit_pcs=frozenset(),
@@ -140,4 +141,4 @@ class FompiADHT:
                                 writes=[], next_pc=A_OP,
                                 regs_row=st.regs[p], extra=extra)
 
-        return (a_op, a_overflow, a_done, a_chain)
+        return handler_table((a_op, a_overflow, a_done, a_chain), PC_NAMES)

@@ -24,19 +24,23 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.engine import (Env, SimState, cs_duration, cs_enter,
-                               cs_exit, finish_instr, leases_expired,
-                               memoized_build, recovery_extra,
-                               think_duration)
+                               cs_exit, finish_instr, handler_table,
+                               leases_expired, memoized_build,
+                               recovery_extra, think_duration)
 from repro.core.programs.meta import SEG_SCRATCH, ProgramMeta
 
 _NOOP = jnp.int32(-1)
 
 # foMPI-Spin PCs.
 S_TRY, S_CS, S_REL, S_DONE, S_REC = 0, 1, 2, 3, 4
+SPIN_PC_NAMES = ("S_TRY", "S_CS", "S_REL", "S_DONE", "S_REC")
 # foMPI-RW PCs.
 W_TRY, W_WAITR, W_CS, W_REL, W_DONE = 0, 1, 2, 3, 4
 R_INC, R_CHECK, R_UNDO, R_CS, R_REL, R_DONE = 5, 6, 7, 8, 9, 10
 W_REC, R_REC, W_DRAIN = 11, 12, 13
+RW_PC_NAMES = ("W_TRY", "W_WAITR", "W_CS", "W_REL", "W_DONE",
+               "R_INC", "R_CHECK", "R_UNDO", "R_CS", "R_REL",
+               "R_DONE", "W_REC", "R_REC", "W_DRAIN")
 
 # Crash recovery (lease-based): lock words store the OWNER'S id (p+1)
 # instead of a bare 1, so a waiter that finds the word held can ask the
@@ -77,7 +81,7 @@ class FompiSpin:
         """Declared program shape for `repro.analysis` (locklint)."""
         return ProgramMeta(
             name="fompi_spin", n_pcs=5, n_regs=self.n_regs,
-            pc_names=("S_TRY", "S_CS", "S_REL", "S_DONE", "S_REC"),
+            pc_names=SPIN_PC_NAMES,
             dead_pcs=frozenset(),
             cs_enter_pcs=frozenset({S_CS}),
             cs_exit_pcs=frozenset({S_REL}),
@@ -152,7 +156,8 @@ class FompiSpin:
                                 regs_row=st.regs[p], window=win,
                                 extra=recovery_extra(dead, ~dead))
 
-        return (s_try, s_cs, s_rel, s_done, s_rec)
+        return handler_table((s_try, s_cs, s_rel, s_done, s_rec),
+                             SPIN_PC_NAMES)
 
 
 class FompiRW:
@@ -186,9 +191,7 @@ class FompiRW:
             dead |= {R_INC, R_CHECK, R_UNDO, R_CS, R_REL, R_DONE, R_REC}
         return ProgramMeta(
             name="fompi_rw", n_pcs=14, n_regs=self.n_regs,
-            pc_names=("W_TRY", "W_WAITR", "W_CS", "W_REL", "W_DONE",
-                      "R_INC", "R_CHECK", "R_UNDO", "R_CS", "R_REL",
-                      "R_DONE", "W_REC", "R_REC", "W_DRAIN"),
+            pc_names=RW_PC_NAMES,
             dead_pcs=frozenset(dead),
             cs_enter_pcs=frozenset({W_CS, R_CS}),
             cs_exit_pcs=frozenset({W_REL, R_REL}),
@@ -366,6 +369,6 @@ class FompiRW:
                                 regs_row=st.regs[p], window=win,
                                 extra=recovery_extra(stale, ~(stale | drained)))
 
-        return (w_try, w_waitr, w_cs, w_rel, w_done,
-                r_inc, r_check, r_undo, r_cs, r_rel, r_done,
-                w_rec, r_rec, w_drain)
+        return handler_table((w_try, w_waitr, w_cs, w_rel, w_done,
+                              r_inc, r_check, r_undo, r_cs, r_rel, r_done,
+                              w_rec, r_rec, w_drain), RW_PC_NAMES)

@@ -1109,7 +1109,7 @@ class HierProgram:
         handlers[REC_TAILFIX] = rec_tailfix
         handlers[REC_DRAIN] = rec_drain
         handlers[R_UNBAR] = r_unbar
-        return tuple(handlers)
+        return engine.handler_table(handlers, PC_NAMES)
 
 
 def rma_rw() -> HierProgram:

@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import engine
+from repro.core import engine, spans
 from repro.core.spec import EXTRA_WORDS, LockSpec
 from repro.core.topology import counter_ranks
 from repro.core.window import build_layout
@@ -117,27 +117,32 @@ class Session:
                  cs_kind: int = 0, think: bool = False,
                  max_events: int = 2_000_000,
                  extra_words: int = EXTRA_WORDS, devices=None):
-        self.spec = spec
-        self.devices = resolve_devices(devices)
-        self.target_acq = int(target_acq)
-        self.cs_kind = int(cs_kind)
-        self.think = bool(think)
-        self.max_events = int(max_events)
-        self.extra_words = int(extra_words)
-        self.machine = spec.machine()
-        self.layout = spec.layout(self.machine, extra_words=extra_words)
-        self.is_writer = spec.roles()
-        self.program = spec.program(self.layout)
-        self.env = engine.make_env(
-            self.machine, self.layout, T_L=spec.T_L, T_R=spec.T_R,
-            is_writer=self.is_writer, target_acq=self.target_acq,
-            cs_kind=self.cs_kind, think=self.think, cost=spec.cost)
-        self.handlers = self.program.build(self.env)
-        self.state0 = engine.init_state(
-            self.env, self.layout, self.program.init_pc(self.env),
-            self.program.n_regs, self.program.init_regs(self.env))
-        self._sweep_fn = None
-        self._shard_fns = {}      # devices tuple -> jitted sharded fn
+        with spans.span("session.build"):
+            self.spec = spec
+            self.devices = resolve_devices(devices)
+            self.target_acq = int(target_acq)
+            self.cs_kind = int(cs_kind)
+            self.think = bool(think)
+            self.max_events = int(max_events)
+            self.extra_words = int(extra_words)
+            with spans.span("session.layout"):
+                self.machine = spec.machine()
+                self.layout = spec.layout(self.machine,
+                                          extra_words=extra_words)
+                self.is_writer = spec.roles()
+            with spans.span("session.handlers"):
+                self.program = spec.program(self.layout)
+                self.env = engine.make_env(
+                    self.machine, self.layout, T_L=spec.T_L, T_R=spec.T_R,
+                    is_writer=self.is_writer, target_acq=self.target_acq,
+                    cs_kind=self.cs_kind, think=self.think, cost=spec.cost)
+                self.handlers = self.program.build(self.env)
+            with spans.span("session.init_state"):
+                self.state0 = engine.init_state(
+                    self.env, self.layout, self.program.init_pc(self.env),
+                    self.program.n_regs, self.program.init_regs(self.env))
+            self._sweep_fn = None
+            self._shard_fns = {}      # devices tuple -> jitted sharded fn
 
     def _devices(self, devices):
         """Per-call `devices=` override (the constructor's value when
@@ -160,15 +165,18 @@ class Session:
         a leading [len(seeds)] axis. With `devices`, the seed batch is
         sharded across them (padded to a device multiple, unpadded in
         the returned Metrics)."""
-        seeds = jnp.asarray(seeds, jnp.int32)
-        devices = self._devices(devices)
-        if devices is None:
-            return engine._run_batch(self.handlers, self.max_events,
-                                     self.state0, seeds)
-        # One-point "lattice": shard the flattened (1 x S) batch.
-        st0 = jax.tree.map(lambda x: x[None], self.state0)
-        m = self._dispatch({}, st0, seeds, devices)
-        return metrics_at(m, 0)
+        with spans.span("session.run_batch"):
+            with spans.span("session.seeds_to_device"):
+                seeds = jnp.asarray(seeds, jnp.int32)
+            devices = self._devices(devices)
+            with spans.span("session.dispatch"):
+                if devices is None:
+                    return engine._run_batch(self.handlers, self.max_events,
+                                             self.state0, seeds)
+                # One-point "lattice": shard the flattened (1 x S) batch.
+                st0 = jax.tree.map(lambda x: x[None], self.state0)
+                m = self._dispatch({}, st0, seeds, devices)
+            return metrics_at(m, 0)
 
     # --------------------------------------------------------- sweeps
     def specs_along(self, axis: str, values) -> list:
@@ -189,10 +197,12 @@ class Session:
         Returns stacked Metrics with leading axes [len(values),
         len(seeds)]; index with `metrics_at(m, k, s)`.
         """
-        specs = self.specs_along(axis, values)
-        seeds = jnp.asarray(seeds, jnp.int32)
-        dyn, st0 = self._sweep_points(axis, specs)
-        return self._dispatch(dyn, st0, seeds, self._devices(devices))
+        with spans.span("session.sweep"):
+            specs = self.specs_along(axis, values)
+            seeds = jnp.asarray(seeds, jnp.int32)
+            with spans.span("session.stack"):
+                dyn, st0 = self._sweep_points(axis, specs)
+            return self._dispatch(dyn, st0, seeds, self._devices(devices))
 
     def grid(self, t_dc, t_l, t_r, *, seeds=(0,),
              devices=_UNSET) -> engine.Metrics:
@@ -216,7 +226,17 @@ class Session:
         t_r = [int(v) for v in t_r]
         if not (t_dc and t_l and t_r):
             raise ValueError("grid axes must be non-empty")
-        seeds = jnp.asarray(seeds, jnp.int32)
+        with spans.span("session.grid"):
+            seeds = jnp.asarray(seeds, jnp.int32)
+            with spans.span("session.stack"):
+                dyn, st0 = self._grid_points(t_dc, t_l, t_r)
+            m = self._dispatch(dyn, st0, seeds, self._devices(devices))
+        shape = (len(t_dc), len(t_l), len(t_r))
+        return engine.Metrics(
+            *(leaf.reshape(shape + leaf.shape[1:]) for leaf in m))
+
+    def _grid_points(self, t_dc, t_l, t_r):
+        """Stacked env overrides + initial states of the lattice."""
         C_pad = max(len(counter_ranks(self.machine, d)) for d in t_dc)
         dyns, states = [], []
         for d in t_dc:
@@ -234,10 +254,7 @@ class Session:
                     states.append(st_d)
         dyn = {k: jnp.stack([dd[k] for dd in dyns]) for k in dyns[0]}
         st0 = jax.tree.map(lambda *xs: jnp.stack(xs), *states)
-        m = self._dispatch(dyn, st0, seeds, self._devices(devices))
-        shape = (len(t_dc), len(t_l), len(t_r))
-        return engine.Metrics(
-            *(leaf.reshape(shape + leaf.shape[1:]) for leaf in m))
+        return dyn, st0
 
     def _layout_dyn(self, T_DC: int, C_pad: int):
         """Padded layout for one T_DC point + the env overrides that
@@ -289,6 +306,7 @@ class Session:
         if devices is None:
             if self._sweep_fn is None:
                 self._sweep_fn = self._build_sweep_fn()
+            spans.note_dispatch(self._sweep_fn, (), (dyn, st0, seeds))
             return self._sweep_fn(dyn, st0, seeds)
         return self._dispatch_sharded(dyn, st0, seeds, devices)
 
@@ -314,6 +332,7 @@ class Session:
         fn = self._shard_fns.get(devices)
         if fn is None:
             fn = self._shard_fns[devices] = self._build_shard_fn(devices)
+        spans.note_dispatch(fn, (), (dyn, st0, idx, sds))
         m = fn(dyn, st0, idx, sds)
         return engine.Metrics(
             *(leaf[:B].reshape((K, S) + leaf.shape[1:]) for leaf in m))
