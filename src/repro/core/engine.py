@@ -197,10 +197,14 @@ class FaultPlan(NamedTuple):
     crash_t[p] (INF = never), optionally revive it at revive_t[p].
 
     Threaded through `SimState` as traced arrays, so a vmapped batch can
-    run a different plan per lane without recompiling. A crash takes
-    effect at the victim's next scheduling point at or after crash_t —
-    the victim's current instruction is NOT executed (its window words
-    go stale exactly as a real process dying between RMA ops), its CS
+    run a different plan per lane without recompiling: one compiled
+    program serves every fault point. A run given no plan at all cannot
+    crash, and compiles the crash-free loop instead (`step_loop`).
+
+    A crash takes effect at the victim's next scheduling point at or
+    after crash_t — the victim's current instruction is NOT executed
+    (its window words go stale exactly as a real process dying between
+    RMA ops), its CS
     occupancy is released for accounting (the lease/fencing assumption:
     a dead holder's effects are fenced once its lease expires), and it
     never runs again until revive_t.
@@ -551,45 +555,143 @@ def _fault_event(st: SimState, p, now) -> SimState:
         clock=now, events=st.events + 1)
 
 
-def step_loop(handlers, max_events: int, st: SimState, seed) -> SimState:
-    """Traceable simulation core: run `st` to completion under `handlers`.
+class StepTable(NamedTuple):
+    """What `step_loop` runs: the handlers its switch holds, the slot in
+    it of every pc, and whether the loop carries the fault branch.
+
+    `slots` is None where the switch holds every pc at its own index.
+    Hashable, so it is a static jit argument: equal tables share one
+    compiled program.
+    """
+
+    handlers: tuple
+    slots: tuple | None
+    faults: bool
+
+
+def unreachable_pcs(program, env: Env, *, faults: bool) -> frozenset:
+    """The pcs of `program` that no run at `env` reaches: its declared
+    `dead_pcs`, and without a fault plan (`faults` False) its
+    `recovery_pcs` too. locklint proves both declarations."""
+    meta = program.meta(env)
+    if faults:
+        return meta.dead_pcs
+    return meta.dead_pcs | meta.recovery_pcs
+
+
+def _trap(p, now, key, st: SimState) -> SimState:
+    """The one slot of every pruned pc. Reaching it means a declaration
+    of `unreachable_pcs` is wrong: under the sanitizer a check names the
+    pc; without it the run ends with the process not done, its state
+    unchanged but for `events`, set past any `max_events`."""
+    if _SANITIZE_TRACING:
+        checkify.check(jnp.bool_(False),
+                       "pc {pc} was declared unreachable in this run",
+                       pc=st.pc[p])
+    return st._replace(events=jnp.int32(np.iinfo(np.int32).max))
+
+
+def prune(handlers: Sequence[Callable], unreachable: frozenset, *,
+          faults: bool) -> StepTable:
+    """The step table of a full handler table: its live handlers, then
+    one shared trap slot that every pc of `unreachable` maps to. Counts
+    the pruned pcs as `program.pruned_pcs`."""
+    live = [pc for pc in range(len(handlers)) if pc not in unreachable]
+    spans.count("program.pruned_pcs", len(handlers) - len(live))
+    if len(live) == len(handlers):
+        return StepTable(tuple(handlers), None, faults)
+    slot = {pc: i for i, pc in enumerate(live)}
+    slots = tuple(slot.get(pc, len(live)) for pc in range(len(handlers)))
+    return StepTable(tuple(handlers[pc] for pc in live) + (_trap,), slots,
+                     faults)
+
+
+def _as_table(table) -> StepTable:
+    """A bare handler table runs every pc, with the fault branch."""
+    if isinstance(table, StepTable):
+        return table
+    return StepTable(tuple(table), None, True)
+
+
+_TABLES = {False: {}, True: {}}
+
+
+def run_table(program, env: Env, *, faults: bool) -> StepTable:
+    """The step table of `program.build(env)` for a run with (`faults`)
+    or without a fault plan, derived once per handler table: cached by
+    the table's identity, so a second run reuses the compiled program."""
+    return memoized_build(
+        _TABLES[faults], program.build(env),
+        lambda handlers: prune(
+            handlers, unreachable_pcs(program, env, faults=faults),
+            faults=faults))
+
+
+def step_loop(table, max_events: int, st: SimState, seed) -> SimState:
+    """Traceable simulation core: run `st` to completion under `table`.
+
+    `table` is a `StepTable`, or a bare handler table, which runs every
+    pc with the fault branch. Each trip the scheduler (named scope
+    `sched`) picks the ready process, and a switch runs its pc's handler
+    (each branch under the scope `handlers`). A table with `faults`
+    wraps the switch in a `lax.cond` against `_fault_event` (scope
+    `fault`), which crashes or revives the process instead; a
+    crash-free table has no such branch, and its loop drops the crashed
+    terms, as nothing crashes.
 
     Plain function (no jit) so callers can embed it under their own
     jit/vmap — `run_sim_batch` vmaps it over seeds, `Session.sweep`
     additionally vmaps it over environment points.
     """
+    handlers, slots, faults = _as_table(table)
+    # The scope `handlers` names each branch, not the switch: under vmap
+    # the switch broadcasts its operands (the env's [P, P] tables among
+    # them) to every lane, which is the loop's cost, not a handler's.
+    # The wrappers are new on every trace, and lax.switch caches traced
+    # branches by function identity, so the checked and plain traces of
+    # one table (`_SANITIZE_TRACING` on and off) never share a jaxpr.
+    branches = tuple(_scoped(h, "handlers") for h in handlers)
     key0 = jax.random.PRNGKey(seed)
+
+    def out_of_play(st):
+        # A crashed-forever process is parked at t_ready == INF; it must
+        # not keep the loop alive (nor may a done process).
+        if not faults:
+            return st.done
+        return st.done | (st.crashed & (st.t_ready >= INF))
 
     def cond(carry):
         st, _ = carry
-        # A crashed-forever process is parked at t_ready == INF; it must
-        # not keep the loop alive (nor may a done process).
-        pending = ~(st.done | (st.crashed & (st.t_ready >= INF)))
-        return jnp.any(pending) & (st.events < max_events)
+        return jnp.any(~out_of_play(st)) & (st.events < max_events)
 
     def body(carry):
         st, key = carry
         with jax.named_scope("sched"):
             key, sub = jax.random.split(key)
-            parked = st.crashed & (st.t_ready >= INF)
-            tr = jnp.where(st.done | parked, INF, st.t_ready)
+            tr = jnp.where(out_of_play(st), INF, st.t_ready)
             p = jnp.argmin(tr).astype(jnp.int32)
             now = tr[p]
-            # Fault injection: a live process whose crash time has come
-            # crashes INSTEAD of executing its instruction; a crashed one
-            # being scheduled is (by the t_ready protocol) due for revive.
-            fault = st.crashed[p] | (now >= st.crash_t[p])
             pc = st.pc[p]
+            if slots is not None:
+                pc = jnp.asarray(slots, jnp.int32)[pc]
+            if faults:
+                # Fault injection: a live process whose crash time has
+                # come crashes INSTEAD of executing its instruction; a
+                # crashed one being scheduled is (by the t_ready
+                # protocol) due for revive.
+                fault = st.crashed[p] | (now >= st.crash_t[p])
 
         def fault_event(op):
             with jax.named_scope("fault"):
                 return _fault_event(op[0], op[1], op[2])
 
         def instruction(op):
-            with jax.named_scope("handlers"):
-                return jax.lax.switch(pc, handlers, op[1], op[2], sub, op[0])
+            return jax.lax.switch(pc, branches, op[1], op[2], sub, op[0])
 
-        st = jax.lax.cond(fault, fault_event, instruction, (st, p, now))
+        if faults:
+            st = jax.lax.cond(fault, fault_event, instruction, (st, p, now))
+        else:
+            st = instruction((st, p, now))
         return st, key
 
     st, _ = jax.lax.while_loop(cond, body, (st, key0))
@@ -613,40 +715,28 @@ def _scoped(handler, scope: str):
     return run
 
 
-@functools.partial(jax.jit, static_argnames=("handlers", "max_events"))
-def _run_jit(handlers, max_events: int, st: SimState, seed) -> SimState:
-    return step_loop(handlers, max_events, st, seed)
+@functools.partial(jax.jit, static_argnames=("table", "max_events"))
+def _run_jit(table, max_events: int, st: SimState, seed) -> SimState:
+    return step_loop(table, max_events, st, seed)
 
 
 _CHECK_ERRORS = checkify.index_checks | checkify.user_checks
 
 
-def _rewrap(handlers):
-    """Fresh closure per handler. lax.switch/while_loop cache traced
-    jaxprs by branch-function identity, and the checked and plain paths
-    trace the SAME handler objects with different `_SANITIZE_TRACING`
-    values — sharing cache entries would either leak un-functionalized
-    `check` primitives into the plain path or silently drop every check
-    from the sanitized one. Distinct wrapper objects split the cache."""
-    return tuple((lambda *a, _h=h: _h(*a)) for h in handlers)
-
-
 @functools.lru_cache(maxsize=MEMO_MAX_ENTRIES)
-def _checked_run(handlers, max_events: int):
-    wrapped = _rewrap(handlers)
+def _checked_run(table, max_events: int):
     return jax.jit(checkify.checkify(
-        lambda st, seed: step_loop(wrapped, max_events, st, seed),
+        lambda st, seed: step_loop(table, max_events, st, seed),
         errors=_CHECK_ERRORS))
 
 
 @functools.lru_cache(maxsize=MEMO_MAX_ENTRIES)
-def _checked_run_batch(handlers, max_events: int):
+def _checked_run_batch(table, max_events: int):
     # checkify cannot wrap a batched while-loop, so the transform order
     # is vmap-of-checkify: each seed's run carries its own error slot
     # and `.throw()` on the batched error reports the first failure.
-    wrapped = _rewrap(handlers)
     checked = checkify.checkify(
-        lambda st, s: step_loop(wrapped, max_events, st, s),
+        lambda st, s: step_loop(table, max_events, st, s),
         errors=_CHECK_ERRORS)
 
     def batched(st, seeds):
@@ -669,11 +759,11 @@ def _call_checked(fn, *args):
     return out
 
 
-def _run(handlers, max_events: int, st: SimState, seed) -> SimState:
+def _run(table, max_events: int, st: SimState, seed) -> SimState:
     if checks_enabled():
-        return _call_checked(_checked_run(handlers, max_events), st, seed)
-    spans.note_dispatch(_run_jit, (handlers, max_events), (st, seed))
-    return _run_jit(handlers, max_events, st, seed)
+        return _call_checked(_checked_run(table, max_events), st, seed)
+    spans.note_dispatch(_run_jit, (table, max_events), (st, seed))
+    return _run_jit(table, max_events, st, seed)
 
 
 def pairwise_sum(x: jnp.ndarray) -> jnp.ndarray:
@@ -721,30 +811,34 @@ def summarize(st: SimState) -> Metrics:
         t_crash=jnp.min(jnp.where(st.crashed, st.crash_t, INF)))
 
 
-@functools.partial(jax.jit, static_argnames=("handlers", "max_events"))
-def _run_batch_jit(handlers, max_events: int, st: SimState,
+@functools.partial(jax.jit, static_argnames=("table", "max_events"))
+def _run_batch_jit(table, max_events: int, st: SimState,
                    seeds: jnp.ndarray) -> Metrics:
-    final = jax.vmap(lambda s: step_loop(handlers, max_events, st, s))(seeds)
+    final = jax.vmap(lambda s: step_loop(table, max_events, st, s))(seeds)
     return jax.vmap(summarize)(final)
 
 
-def _run_batch(handlers, max_events: int, st: SimState,
+def _run_batch(table, max_events: int, st: SimState,
                seeds: jnp.ndarray) -> Metrics:
     if checks_enabled():
-        return _call_checked(_checked_run_batch(handlers, max_events),
+        return _call_checked(_checked_run_batch(table, max_events),
                              st, seeds)
-    spans.note_dispatch(_run_batch_jit, (handlers, max_events), (st, seeds))
-    return _run_batch_jit(handlers, max_events, st, seeds)
+    spans.note_dispatch(_run_batch_jit, (table, max_events), (st, seeds))
+    return _run_batch_jit(table, max_events, st, seeds)
 
 
 def run_sim(program, env: Env, layout: Layout, *, seed=0,
             max_events: int = 2_000_000,
             fault: FaultPlan | None = None) -> Metrics:
-    """Run a protocol program to completion and summarize metrics."""
-    handlers = program.build(env)
+    """Run a protocol program to completion and summarize metrics.
+
+    Without `fault` the run cannot crash, so it takes the crash-free
+    loop; any `FaultPlan`, `FaultPlan.none` included, takes the full
+    loop (see `step_loop`)."""
+    table = run_table(program, env, faults=fault is not None)
     st = init_state(env, layout, program.init_pc(env), program.n_regs,
                     program.init_regs(env), fault=fault)
-    return summarize(_run(handlers, max_events, st, seed))
+    return summarize(_run(table, max_events, st, seed))
 
 
 def run_sim_batch(program, env: Env, layout: Layout, *, seeds,
@@ -754,10 +848,11 @@ def run_sim_batch(program, env: Env, layout: Layout, *, seeds,
 
     vmap over seeds yields one distinct schedule interleaving per seed
     (the module docstring's SPIN-checking analogue). Returns Metrics
-    whose leaves carry a leading [len(seeds)] axis.
+    whose leaves carry a leading [len(seeds)] axis. `fault` selects
+    the loop as in `run_sim`.
     """
-    handlers = program.build(env)
+    table = run_table(program, env, faults=fault is not None)
     st = init_state(env, layout, program.init_pc(env), program.n_regs,
                     program.init_regs(env), fault=fault)
-    return _run_batch(handlers, max_events, st,
+    return _run_batch(table, max_events, st,
                       jnp.asarray(seeds, jnp.int32))

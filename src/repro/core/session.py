@@ -36,10 +36,17 @@ single-device dispatch because entries never interact (the vmapped
 `engine.pairwise_sum` fixes the one float reduction's order).
 `devices=None` (the default) keeps the classic single-device dispatch.
 
-Seed-level caching: the jitted program is cached per (handlers,
+Crash-free loop: a Session carries no `engine.FaultPlan`, so nothing in
+it crashes. Every execution shape runs `engine.step_loop` without the
+fault branch, over a switch that holds only the pcs a crash-free run
+reaches (`engine.unreachable_pcs`: the program's declared dead and
+recovery pcs go to one trap slot).
+
+Seed-level caching: the jitted program is cached per (step table,
 max_events) by JAX, and handlers are cached per environment by the
 program, so repeated `run`/`run_batch` calls on one Session never
-recompile. Sharded dispatch functions are cached per device tuple.
+recompile. Sweep and sharded dispatch functions are cached per set of
+pruned pcs (and device tuple).
 """
 from __future__ import annotations
 
@@ -137,12 +144,14 @@ class Session:
                     is_writer=self.is_writer, target_acq=self.target_acq,
                     cs_kind=self.cs_kind, think=self.think, cost=spec.cost)
                 self.handlers = self.program.build(self.env)
+                self.table = engine.run_table(self.program, self.env,
+                                              faults=False)
             with spans.span("session.init_state"):
                 self.state0 = engine.init_state(
                     self.env, self.layout, self.program.init_pc(self.env),
                     self.program.n_regs, self.program.init_regs(self.env))
-            self._sweep_fn = None
-            self._shard_fns = {}      # devices tuple -> jitted sharded fn
+            self._sweep_fns = {}      # pruned pcs -> jitted sweep fn
+            self._shard_fns = {}      # (devices, pruned pcs) -> jitted fn
 
     def _devices(self, devices):
         """Per-call `devices=` override (the constructor's value when
@@ -154,8 +163,7 @@ class Session:
     def run_state(self, seed: int = 0) -> engine.SimState:
         """One schedule to completion; returns the final simulator state
         (for invariant checks that need more than Metrics)."""
-        return engine._run(self.handlers, self.max_events, self.state0,
-                           seed)
+        return engine._run(self.table, self.max_events, self.state0, seed)
 
     def run(self, seed: int = 0) -> engine.Metrics:
         return engine.summarize(self.run_state(seed))
@@ -171,11 +179,12 @@ class Session:
             devices = self._devices(devices)
             with spans.span("session.dispatch"):
                 if devices is None:
-                    return engine._run_batch(self.handlers, self.max_events,
+                    return engine._run_batch(self.table, self.max_events,
                                              self.state0, seeds)
                 # One-point "lattice": shard the flattened (1 x S) batch.
                 st0 = jax.tree.map(lambda x: x[None], self.state0)
-                m = self._dispatch({}, st0, seeds, devices)
+                m = self._dispatch({}, st0, seeds, devices,
+                                   self._unreachable([self.env]))
             return metrics_at(m, 0)
 
     # --------------------------------------------------------- sweeps
@@ -201,8 +210,9 @@ class Session:
             specs = self.specs_along(axis, values)
             seeds = jnp.asarray(seeds, jnp.int32)
             with spans.span("session.stack"):
-                dyn, st0 = self._sweep_points(axis, specs)
-            return self._dispatch(dyn, st0, seeds, self._devices(devices))
+                dyn, st0, pruned = self._sweep_points(axis, specs)
+            return self._dispatch(dyn, st0, seeds, self._devices(devices),
+                                  pruned)
 
     def grid(self, t_dc, t_l, t_r, *, seeds=(0,),
              devices=_UNSET) -> engine.Metrics:
@@ -229,14 +239,22 @@ class Session:
         with spans.span("session.grid"):
             seeds = jnp.asarray(seeds, jnp.int32)
             with spans.span("session.stack"):
-                dyn, st0 = self._grid_points(t_dc, t_l, t_r)
-            m = self._dispatch(dyn, st0, seeds, self._devices(devices))
+                dyn, st0, pruned = self._grid_points(t_dc, t_l, t_r)
+            m = self._dispatch(dyn, st0, seeds, self._devices(devices),
+                               pruned)
         shape = (len(t_dc), len(t_l), len(t_r))
         return engine.Metrics(
             *(leaf.reshape(shape + leaf.shape[1:]) for leaf in m))
 
+    def _unreachable(self, envs) -> frozenset:
+        """The pcs that no crash-free run at any of `envs` reaches."""
+        return frozenset.intersection(*(
+            engine.unreachable_pcs(self.program, env, faults=False)
+            for env in envs))
+
     def _grid_points(self, t_dc, t_l, t_r):
-        """Stacked env overrides + initial states of the lattice."""
+        """Stacked env overrides + initial states of the lattice, and
+        the pcs no point reaches."""
         C_pad = max(len(counter_ranks(self.machine, d)) for d in t_dc)
         dyns, states = [], []
         for d in t_dc:
@@ -252,9 +270,11 @@ class Session:
                     dyns.append(dict(ldyn, **_tl_dyn(spec_k),
                                      **_tr_dyn(spec_k)))
                     states.append(st_d)
+        pruned = self._unreachable(
+            dataclasses.replace(self.env, **dd) for dd in dyns)
         dyn = {k: jnp.stack([dd[k] for dd in dyns]) for k in dyns[0]}
         st0 = jax.tree.map(lambda *xs: jnp.stack(xs), *states)
-        return dyn, st0
+        return dyn, st0, pruned
 
     def _layout_dyn(self, T_DC: int, C_pad: int):
         """Padded layout for one T_DC point + the env overrides that
@@ -272,10 +292,11 @@ class Session:
         return layout, dyn
 
     def _sweep_points(self, axis: str, specs):
-        """Stacked per-point env overrides + initial states (numpy)."""
+        """Stacked per-point env overrides + initial states (numpy), and
+        the pcs no point reaches."""
         C_pad = (max(len(counter_ranks(self.machine, s.T_DC))
                      for s in specs) if axis == "T_DC" else None)
-        dyns, states = [], []
+        dyns, states, envs = [], [], []
         for s in specs:
             layout = self.layout
             if axis == "T_R":
@@ -294,23 +315,27 @@ class Session:
                 env_k, layout, self.program.init_pc(env_k),
                 self.program.n_regs, self.program.init_regs(env_k)))
             dyns.append(dyn)
+            envs.append(env_k)
         dyn = {k: jnp.stack([d[k] for d in dyns]) for k in dyns[0]}
         st0 = jax.tree.map(lambda *xs: jnp.stack(xs), *states)
-        return dyn, st0
+        return dyn, st0, self._unreachable(envs)
 
-    def _dispatch(self, dyn, st0, seeds, devices=None) -> engine.Metrics:
-        """Run the stacked points × seeds batch; Metrics leaves come
-        back with leading [K, S] axes. `devices=None` is the classic
-        single-device dispatch; otherwise the flattened (K × S) batch
-        is sharded across the device tuple."""
+    def _dispatch(self, dyn, st0, seeds, devices, pruned) -> engine.Metrics:
+        """Run the stacked points × seeds batch, with the pcs `pruned`
+        sent to the trap; Metrics leaves come back with leading [K, S]
+        axes. `devices=None` is the classic single-device dispatch;
+        otherwise the flattened (K × S) batch is sharded across the
+        device tuple."""
         if devices is None:
-            if self._sweep_fn is None:
-                self._sweep_fn = self._build_sweep_fn()
-            spans.note_dispatch(self._sweep_fn, (), (dyn, st0, seeds))
-            return self._sweep_fn(dyn, st0, seeds)
-        return self._dispatch_sharded(dyn, st0, seeds, devices)
+            fn = self._sweep_fns.get(pruned)
+            if fn is None:
+                fn = self._sweep_fns[pruned] = self._build_sweep_fn(pruned)
+            spans.note_dispatch(fn, (), (dyn, st0, seeds))
+            return fn(dyn, st0, seeds)
+        return self._dispatch_sharded(dyn, st0, seeds, devices, pruned)
 
-    def _dispatch_sharded(self, dyn, st0, seeds, devices) -> engine.Metrics:
+    def _dispatch_sharded(self, dyn, st0, seeds, devices,
+                          pruned) -> engine.Metrics:
         """Flatten (points × seeds), pad to a device multiple with dead
         entries, shard, and unpad the Metrics.
 
@@ -329,15 +354,16 @@ class Session:
         if pad:
             idx = jnp.concatenate([idx, jnp.zeros(pad, jnp.int32)])
             sds = jnp.concatenate([sds, jnp.broadcast_to(seeds[:1], (pad,))])
-        fn = self._shard_fns.get(devices)
+        fn = self._shard_fns.get((devices, pruned))
         if fn is None:
-            fn = self._shard_fns[devices] = self._build_shard_fn(devices)
+            fn = self._shard_fns[devices, pruned] = self._build_shard_fn(
+                devices, pruned)
         spans.note_dispatch(fn, (), (dyn, st0, idx, sds))
         m = fn(dyn, st0, idx, sds)
         return engine.Metrics(
             *(leaf[:B].reshape((K, S) + leaf.shape[1:]) for leaf in m))
 
-    def _point_entry(self, dyn, st0, i, seed):
+    def _point_entry(self, pruned, dyn, st0, i, seed):
         """One flattened (point, seed) entry: realize point i's env and
         run seed's schedule to completion (traceable)."""
         env_k = dataclasses.replace(
@@ -345,11 +371,12 @@ class Session:
         st_k = jax.tree.map(lambda x: x[i], st0)
         # _build, not build: the memoizing build() would retain this
         # traced env (and its tracers) past the trace.
-        handlers = self.program._build(env_k)
-        final = engine.step_loop(handlers, self.max_events, st_k, seed)
+        table = engine.prune(self.program._build(env_k), pruned,
+                             faults=False)
+        final = engine.step_loop(table, self.max_events, st_k, seed)
         return engine.summarize(final)
 
-    def _build_shard_fn(self, devices):
+    def _build_shard_fn(self, devices, pruned):
         """Jitted sharded dispatch over a 1D mesh of `devices`: each
         device runs its contiguous chunk of the flattened batch through
         one vmapped entry body (ONE trace — the point program is built
@@ -360,7 +387,7 @@ class Session:
 
         def tile(dyn, st0, idx, seeds):
             return jax.vmap(functools.partial(
-                self._point_entry, dyn, st0))(idx, seeds)
+                self._point_entry, pruned, dyn, st0))(idx, seeds)
 
         P = jax.sharding.PartitionSpec
         # Every output is explicitly batch-sharded, so the varying-axes
@@ -369,7 +396,7 @@ class Session:
             tile, mesh=mesh, in_specs=(P(), P(), P("batch"), P("batch")),
             out_specs=P("batch"), check_vma=False))
 
-    def _build_sweep_fn(self):
+    def _build_sweep_fn(self, pruned):
         program, env, max_events = self.program, self.env, self.max_events
 
         @jax.jit
@@ -378,9 +405,10 @@ class Session:
                 env_k = dataclasses.replace(env, **dyn_k)
                 # _build, not build: the memoizing build() would retain
                 # this traced env (and its tracers) past the trace.
-                handlers = program._build(env_k)
+                table = engine.prune(program._build(env_k), pruned,
+                                     faults=False)
                 final = jax.vmap(functools.partial(
-                    engine.step_loop, handlers, max_events, st0_k))(seeds)
+                    engine.step_loop, table, max_events, st0_k))(seeds)
                 return jax.vmap(engine.summarize)(final)
             return jax.vmap(point)(dyn, st0)
 

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import LockSpec, Session, spans
+from repro.core import LockSpec, Session, engine, spans
 from repro.core.programs import hier
 
 
@@ -126,13 +126,13 @@ def rw16():
     builds = spans.counters()["program.builds"]
     sess = Session(spec, target_acq=2)
     built = spans.counters()["program.builds"] - builds
-    dead = sess.program.meta(sess.env).dead_pcs
+    pruned = engine.unreachable_pcs(sess.program, sess.env, faults=False)
     jax.block_until_ready(sess.run_batch(np.arange(3)))
     records = spans.records()
     del sess
     gc.collect()
     return {"records": records, "scopes": spans.op_scopes(),
-            "dead": dead, "built": built}
+            "pruned": pruned, "built": built}
 
 
 def test_session_spans_nest_as_documented(rw16):
@@ -165,8 +165,9 @@ def test_every_handler_op_carries_its_pc_scope(rw16):
             # selecting among the handlers' results.
             assert s.rsplit("/", 1)[1] in ("clamp", "select_n"), s
     live = {hier.PC_NAMES[pc] for pc in range(hier.N_PCS)
-            if pc not in rw16["dead"]}
-    assert live <= pcs <= set(hier.PC_NAMES)
+            if pc not in rw16["pruned"]}
+    # The crash-free program holds the live handlers and no other.
+    assert pcs == live
 
 
 def test_scoped_handlers_keep_their_module_and_name():

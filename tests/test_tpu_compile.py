@@ -68,6 +68,6 @@ def test_batched_simulator_compiles_for_v5e(one_chip):
                       sess.state0)
     seeds = _spec((8,), jnp.int32, one_chip)
     compiled = engine._run_batch_jit.lower(
-        sess.handlers, sess.max_events, st, seeds).compile()
+        sess.table, sess.max_events, st, seeds).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1 << 30
