@@ -24,7 +24,9 @@ the fompi kinds -- the hierarchical queue locks do not support revive
 successors); see tests/test_faults.py for the revive path.
 
 Results are simulated microseconds under the calibrated Aries-class
-cost model; the lease is the `make_env` default (2.0 us).
+cost model; the lease is the `make_env` default (2.0 us). Each kind's
+runs (crash times x seeds) are one `Session.run_batch` call with one
+`FaultPlan` per lane.
 """
 from __future__ import annotations
 
@@ -65,26 +67,20 @@ def bench_faults(*, quick: bool = False, target_acq: int = 6):
     rows = []
     for kind, P, victim, spec_kwargs in FAULT_POINTS:
         sess = _session(kind, P, spec_kwargs, target_acq=target_acq)
-        seeds = range(1 if quick else P)
-        recovery_us = []
-        n_recovered = reclaims = retries = violations = 0
-        all_completed = True
-        n_runs = 0
-        for t in crash_times:
-            fault = FaultPlan.single(P, victim, t)
-            for seed in seeds:
-                m = engine.run_sim(sess.program, sess.env, sess.layout,
-                                   seed=seed, max_events=sess.max_events,
-                                   fault=fault)
-                n_runs += 1
-                violations += int(m.violations)
-                reclaims += int(m.reclaims)
-                retries += int(m.recovery_retries)
-                all_completed &= bool(m.completed)
-                t_rec, t_crash = float(m.t_recover), float(m.t_crash)
-                if t_rec < float(engine.INF):
-                    n_recovered += 1
-                    recovery_us.append(t_rec - t_crash)
+        seeds = np.tile(np.arange(1 if quick else P), len(crash_times))
+        times = np.repeat(crash_times, len(seeds) // len(crash_times))
+        m = sess.run_batch(seeds, faults=FaultPlan.lanes(
+            P, np.full(len(seeds), victim), times))
+        n_runs = len(seeds)
+        violations = int(np.sum(m.violations))
+        reclaims = int(np.sum(m.reclaims))
+        retries = int(np.sum(m.recovery_retries))
+        all_completed = bool(np.all(m.completed))
+        t_rec, t_crash = np.asarray(m.t_recover), np.asarray(m.t_crash)
+        recovered = t_rec < float(engine.INF)
+        n_recovered = int(recovered.sum())
+        recovery_us = [float(r) - float(c) for r, c in
+                       zip(t_rec[recovered], t_crash[recovered])]
         pcts = (np.percentile(recovery_us, (50, 90, 99))
                 if recovery_us else np.zeros(3))
         rows.append({

@@ -5,8 +5,8 @@
 
 One process drives the main path through the entry points users call:
 `LockSpec` -> `Session.run_batch` / `Session.grid` for the simulator,
-`engine.run_sim_batch` with a `FaultPlan` for crash recovery, and
-`BatchedDHT` over the compiled `dht_probe` kernel.
+`Session.run_batch` with one `FaultPlan` per lane for crash recovery,
+and `BatchedDHT` over the compiled `dht_probe` kernel.
 
   a. paper scale: rma_rw and rma_mcs at P=1024 (64 nodes x 16), 8 seeds;
   b. the rma_rw P=64 batch on the chip against the same batch on the
@@ -241,11 +241,9 @@ def phase_crash(timer, sess: Session, *, seeds=8, t_crash=2.0):
     lease = sess.env.lease
     print(f"d. crash recovery: rma_rw P={P}, writer {victim} crashes at "
           f"{t_crash} us, lease {lease} us, {seeds} seeds", flush=True)
-    plan = engine.FaultPlan.single(P, victim, t_crash)
-    m = timer.cold_warm("run_sim_batch with FaultPlan", lambda: (
-        engine.run_sim_batch(sess.program, sess.env, sess.layout,
-                             seeds=np.arange(seeds),
-                             max_events=sess.max_events, fault=plan)))
+    plan = engine.FaultPlan.lanes(P, [victim] * seeds, [t_crash] * seeds)
+    m = timer.cold_warm("run_batch with FaultPlans", lambda: (
+        sess.run_batch(np.arange(seeds), faults=plan)))
     v, c = np.asarray(m.violations), np.asarray(m.completed)
     crashed = np.asarray(m.n_crashed)
     t_c, t_r = np.asarray(m.t_crash), np.asarray(m.t_recover)

@@ -200,6 +200,8 @@ class FaultPlan(NamedTuple):
     run a different plan per lane without recompiling: one compiled
     program serves every fault point. A run given no plan at all cannot
     crash, and compiles the crash-free loop instead (`step_loop`).
+    `lanes` builds one plan per lane of a batch (leaves [S, P]), as
+    `Session.run_batch(faults=)` takes them.
 
     A crash takes effect at the victim's next scheduling point at or
     after crash_t — the victim's current instruction is NOT executed
@@ -207,7 +209,8 @@ class FaultPlan(NamedTuple):
     RMA ops), its CS
     occupancy is released for accounting (the lease/fencing assumption:
     a dead holder's effects are fenced once its lease expires), and it
-    never runs again until revive_t.
+    never runs again until revive_t. A victim that has finished its
+    acquires by crash_t has no such scheduling point and never crashes.
     """
 
     crash_t: jnp.ndarray    # float32 [P]
@@ -227,6 +230,21 @@ class FaultPlan(NamedTuple):
         if revive_at is not None:
             revive = revive.at[victim].set(jnp.float32(revive_at))
         return cls(crash, revive)
+
+    @classmethod
+    def lanes(cls, P: int, victims, times) -> "FaultPlan":
+        """One plan per lane: lane s crashes process victims[s] at
+        times[s] and never revives it. Leaves are [S, P] host arrays."""
+        victims = np.asarray(victims)
+        times = np.asarray(times, np.float32)
+        if victims.ndim != 1 or victims.shape != times.shape:
+            raise ValueError(f"victims {victims.shape} and times "
+                             f"{times.shape} must be one entry per lane")
+        if victims.size and not (0 <= victims.min() and victims.max() < P):
+            raise ValueError(f"victims must lie in [0, {P})")
+        crash = np.full((victims.size, P), float(INF), np.float32)
+        crash[np.arange(victims.size), victims] = times
+        return cls(crash, np.full_like(crash, float(INF)))
 
 
 def leases_expired(env: Env, st: SimState, now) -> jnp.ndarray:
@@ -619,12 +637,18 @@ _TABLES = {False: {}, True: {}}
 def run_table(program, env: Env, *, faults: bool) -> StepTable:
     """The step table of `program.build(env)` for a run with (`faults`)
     or without a fault plan, derived once per handler table: cached by
-    the table's identity, so a second run reuses the compiled program."""
-    return memoized_build(
-        _TABLES[faults], program.build(env),
-        lambda handlers: prune(
-            handlers, unreachable_pcs(program, env, faults=faults),
-            faults=faults))
+    the table's identity, so a second run reuses the compiled program.
+    In a table with `faults`, the handlers of the program's
+    `recovery_pcs` also run under the named scope `recovery`."""
+    def derive(handlers):
+        if faults:
+            recovery = program.meta(env).recovery_pcs
+            handlers = tuple(_scoped(h, "recovery") if pc in recovery
+                             else h for pc, h in enumerate(handlers))
+        return prune(handlers, unreachable_pcs(program, env, faults=faults),
+                     faults=faults)
+
+    return memoized_build(_TABLES[faults], program.build(env), derive)
 
 
 def step_loop(table, max_events: int, st: SimState, seed) -> SimState:
@@ -827,6 +851,38 @@ def _run_batch(table, max_events: int, st: SimState,
     return _run_batch_jit(table, max_events, st, seeds)
 
 
+def _lane(table, max_events: int, st: SimState, seed, crash_t, revive_t):
+    """One lane of a batch with per-lane fault plans."""
+    return step_loop(table, max_events,
+                     st._replace(crash_t=crash_t, revive_t=revive_t), seed)
+
+
+@functools.partial(jax.jit, static_argnames=("table", "max_events"))
+def _run_lanes_jit(table, max_events: int, st: SimState, seeds, crash_t,
+                   revive_t) -> Metrics:
+    final = jax.vmap(functools.partial(_lane, table, max_events, st))(
+        seeds, crash_t, revive_t)
+    return jax.vmap(summarize)(final)
+
+
+def run_lanes(table, max_events: int, st: SimState, seeds: jnp.ndarray,
+              plan: FaultPlan) -> Metrics:
+    """The batched dispatch of runs that carry fault plans: lane s runs
+    seed `seeds[s]` under the plan `plan.crash_t[s]`, `plan.revive_t[s]`
+    (leaves [S, P]) from `st`, whose own plan is replaced. Metrics
+    leaves gain a leading [S] axis. Under the sanitizer each lane is a
+    checked single run: checkify cannot wrap a batched while-loop."""
+    if checks_enabled():
+        checked = _checked_run(table, max_events)
+        runs = [summarize(_call_checked(
+            checked, st._replace(crash_t=c, revive_t=r), s))
+            for s, c, r in zip(seeds, plan.crash_t, plan.revive_t)]
+        return jax.tree.map(lambda *xs: jnp.stack(xs), *runs)
+    args = (st, seeds, plan.crash_t, plan.revive_t)
+    spans.note_dispatch(_run_lanes_jit, (table, max_events), args)
+    return _run_lanes_jit(table, max_events, *args)
+
+
 def run_sim(program, env: Env, layout: Layout, *, seed=0,
             max_events: int = 2_000_000,
             fault: FaultPlan | None = None) -> Metrics:
@@ -849,10 +905,17 @@ def run_sim_batch(program, env: Env, layout: Layout, *, seeds,
     vmap over seeds yields one distinct schedule interleaving per seed
     (the module docstring's SPIN-checking analogue). Returns Metrics
     whose leaves carry a leading [len(seeds)] axis. `fault` selects
-    the loop as in `run_sim`.
+    the loop as in `run_sim`; every lane runs under it, through the
+    per-lane dispatch `run_lanes`.
     """
-    table = run_table(program, env, faults=fault is not None)
+    seeds = jnp.asarray(seeds, jnp.int32)
     st = init_state(env, layout, program.init_pc(env), program.n_regs,
-                    program.init_regs(env), fault=fault)
-    return _run_batch(table, max_events, st,
-                      jnp.asarray(seeds, jnp.int32))
+                    program.init_regs(env))
+    if fault is None:
+        return _run_batch(run_table(program, env, faults=False),
+                          max_events, st, seeds)
+    lanes = (seeds.shape[0], env.P)
+    plan = FaultPlan(*(jnp.broadcast_to(jnp.asarray(x, jnp.float32), lanes)
+                       for x in fault))
+    return run_lanes(run_table(program, env, faults=True), max_events, st,
+                     seeds, plan)

@@ -799,8 +799,10 @@ class HierProgram:
                  successor and predecessor share every ancestor element
                  node, so the adopted continuation's addresses resolve
                  identically.
-              4. the dead froze inside the CS with the full path held —
-                 take the CS.
+              4. the dead froze inside the CS with the full path held,
+                 or in WA_SPIN below this level on a local pass it had
+                 not consumed (the pass hands over the whole hold, so
+                 it would have entered the CS next) — take the CS.
               5. the dead froze mid-release BEFORE its root grant (the
                  release walks leaf->root reading successors, grants
                  the root first, then unwinds back down) — it still
@@ -850,8 +852,12 @@ class HierProgram:
                          | ((dpc == WA_START_PARENT) & (dL == lvl))
                          | (dpc == W_SCTW_FLAG) | (dpc == W_SCTW_VERIFY))
             adopt = ~own_granted & pred_dead & ~inherit & operating
+            d_lvl = jnp.clip(dL, 0, Nlv - 1)
+            passed_below = ((dpc == WA_SPIN) & (dL > lvl)
+                            & (st.window[sw(d_lvl, ent(st.regs[d], d_lvl,
+                                                       d))] >= 1))
             take_cs = (~own_granted & pred_dead & ~inherit & ~adopt
-                       & (dpc == CS))
+                       & ((dpc == CS) | passed_below))
             # Release side. The release grants nothing until ROOT_PASS /
             # the root tail CAS executes: before that (WR_* descent and
             # every ROOT_* pc) the dead still holds ALL levels. Once it
@@ -1003,10 +1009,18 @@ class HierProgram:
             back = jnp.where(at_root, ROOT_WAITSUCC, UNW_WAIT)
             nxt = jnp.where(repaired, UNW_CHECK,
                             jnp.where(wait_mid, REC_TAILFIX, back))
-            r = r.at[UL].set(jnp.where(repaired,
-                                       jnp.maximum(r[UL], lvl + 1),
-                                       r[UL]))
-            dur = env.lat_atomic(p, t) + env.lat_plain(p, wsucc)
+            # The unwind resumes one level below the repaired one. Set,
+            # never raised: entered from ROOT_GETSUCC, UL still holds the
+            # CS's reset value N, and unwinding from there would skip
+            # every level below the root and strand a successor that
+            # linked after WR_READ.
+            r = r.at[UL].set(jnp.where(repaired, lvl + 1, r[UL]))
+            # An atomic on the tail, and a plain access of the word that
+            # settles the step: the grant word of the dead node's
+            # successor, or the dead node's NEXT word, read empty.
+            settles = jnp.where(dn != NULL, wsucc,
+                                nw(lvl, jnp.maximum(e_d, 0)))
+            dur = env.lat_atomic(p, t) + env.lat_plain(p, settles)
             return finish_instr(env, st, p, now, key, dur=dur,
                                 hot_word=jnp.where(repaired, t, _NOOP),
                                 writes=[t, wsucc, nw(lvl, e_d)],

@@ -9,7 +9,8 @@ jitted simulator once, and then offers three execution shapes:
     stacked Metrics ([S] leading axis). One seed = one distinct
     schedule interleaving, so a batch is the executable analogue of the
     paper's SPIN model checking (§4.4) — and of its throughput error
-    bars.
+    bars. `run_batch(seeds, faults=plan)` gives each lane its own
+    crash plan (`engine.FaultPlan`, leaves [S, P]).
   * `sweep(axis, values, seeds=...)` — jit-batched scan over one axis
     of the paper's parameter space as a SINGLE dispatch vmapped over
     (points x seeds). `T_L`, `T_R`, and `writer_fraction` only change
@@ -37,10 +38,13 @@ single-device dispatch because entries never interact (the vmapped
 `devices=None` (the default) keeps the classic single-device dispatch.
 
 Crash-free loop: a Session carries no `engine.FaultPlan`, so nothing in
-it crashes. Every execution shape runs `engine.step_loop` without the
-fault branch, over a switch that holds only the pcs a crash-free run
-reaches (`engine.unreachable_pcs`: the program's declared dead and
-recovery pcs go to one trap slot).
+it crashes unless a call passes plans. Every execution shape, and
+`run_batch` without `faults`, runs `engine.step_loop` without the fault
+branch, over a switch that holds only the pcs a crash-free run reaches
+(`engine.unreachable_pcs`: the program's declared dead and recovery pcs
+go to one trap slot). `run_batch(seeds, faults=plan)` runs the full
+loop, with the fault branch and the recovery handlers, compiled on the
+first such call and reused by every later one.
 
 Seed-level caching: the jitted program is cached per (step table,
 max_events) by JAX, and handlers are cached per environment by the
@@ -152,6 +156,7 @@ class Session:
                     self.program.n_regs, self.program.init_regs(self.env))
             self._sweep_fns = {}      # pruned pcs -> jitted sweep fn
             self._shard_fns = {}      # (devices, pruned pcs) -> jitted fn
+            self.fault_table = None   # the full loop's, on first use
 
     def _devices(self, devices):
         """Per-call `devices=` override (the constructor's value when
@@ -168,15 +173,26 @@ class Session:
     def run(self, seed: int = 0) -> engine.Metrics:
         return engine.summarize(self.run_state(seed))
 
-    def run_batch(self, seeds, *, devices=_UNSET) -> engine.Metrics:
+    def run_batch(self, seeds, *, faults: engine.FaultPlan | None = None,
+                  devices=_UNSET) -> engine.Metrics:
         """Execute all seeds in one jitted dispatch; Metrics leaves gain
         a leading [len(seeds)] axis. With `devices`, the seed batch is
         sharded across them (padded to a device multiple, unpadded in
-        the returned Metrics)."""
+        the returned Metrics).
+
+        `faults` is one crash plan per lane: a `FaultPlan` whose
+        `crash_t` and `revive_t` are [len(seeds), P] (`FaultPlan.lanes`
+        builds one). Lane s runs seed s under plan s through the full
+        loop (`engine.run_table(..., faults=True)`), compiled once for
+        the Session: later calls with other plans reuse it. Without
+        `faults` the call runs the crash-free loop. Plans cannot yet be
+        sharded: `faults` with `devices` raises NotImplementedError."""
         with spans.span("session.run_batch"):
             with spans.span("session.seeds_to_device"):
                 seeds = jnp.asarray(seeds, jnp.int32)
             devices = self._devices(devices)
+            if faults is not None:
+                return self._run_lanes(seeds, faults, devices)
             with spans.span("session.dispatch"):
                 if devices is None:
                     return engine._run_batch(self.table, self.max_events,
@@ -186,6 +202,32 @@ class Session:
                 m = self._dispatch({}, st0, seeds, devices,
                                    self._unreachable([self.env]))
             return metrics_at(m, 0)
+
+    def _run_lanes(self, seeds, faults: engine.FaultPlan,
+                   devices) -> engine.Metrics:
+        """`run_batch` with one fault plan per lane."""
+        lanes = (seeds.shape[0], self.spec.P)
+        crash_t, revive_t = (np.asarray(x, np.float32) for x in faults)
+        if crash_t.shape != lanes or revive_t.shape != lanes:
+            raise ValueError(
+                f"faults must hold one plan per lane: crash_t and revive_t "
+                f"of shape {lanes}, got {crash_t.shape} and "
+                f"{revive_t.shape}")
+        if devices is not None:
+            raise NotImplementedError(
+                "run_batch cannot shard fault plans over devices; pass "
+                "devices=None")
+        spans.count("session.fault_lanes",
+                    int(np.any(crash_t < float(engine.INF), axis=1).sum()))
+        with spans.span("session.faults_to_device"):
+            plan = engine.FaultPlan(jnp.asarray(crash_t),
+                                    jnp.asarray(revive_t))
+        if self.fault_table is None:
+            self.fault_table = engine.run_table(self.program, self.env,
+                                                faults=True)
+        with spans.span("session.dispatch"):
+            return engine.run_lanes(self.fault_table, self.max_events,
+                                    self.state0, seeds, plan)
 
     # --------------------------------------------------------- sweeps
     def specs_along(self, axis: str, values) -> list:

@@ -4,7 +4,8 @@ The TPU compiler refuses what the CPU and the Pallas interpreter accept:
 block shapes off the (8, 128) tiling, kernels over the fast-memory
 limit, programs that do not fit the device. These tests compile the
 `dht_probe` kernels at the chip smoke's sizes and the batched simulator
-at the paper's largest P for one v5e chip. Nothing runs.
+at the paper's largest P for one v5e chip, without and with one fault
+plan per lane. Nothing runs.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, so every pytest worker must
@@ -71,3 +72,19 @@ def test_batched_simulator_compiles_for_v5e(one_chip):
         sess.table, sess.max_events, st, seeds).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1 << 30
+
+
+def test_batched_fault_loop_compiles_for_v5e(one_chip):
+    """The full loop with one fault plan per lane, as
+    `Session.run_batch(faults=)` dispatches it at paper scale."""
+    sess = Session(LockSpec.paper_default("rma_rw", 1024,
+                                          writer_fraction=0.02),
+                   target_acq=4)
+    st = jax.tree.map(lambda x: _spec(x.shape, x.dtype, one_chip),
+                      sess.state0)
+    seeds = _spec((8,), jnp.int32, one_chip)
+    plan = _spec((8, 1024), jnp.float32, one_chip)
+    table = engine.run_table(sess.program, sess.env, faults=True)
+    compiled = engine._run_lanes_jit.lower(
+        table, sess.max_events, st, seeds, plan, plan).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
