@@ -11,7 +11,7 @@ of the canonical (timing-free) state space.
 Canonical states and why they are sound:
 
   * The engine's blocking is "sleep with a backoff timeout": a blocked
-    process always keeps a finite `t_ready` (engine.finish_instr), so
+    process always keeps a finite `t_ready` (engine.apply_effect), so
     wake-on-write only changes *when* it retries, never *whether* it
     can. The canonical state therefore drops `blocked_a/b` entirely and
     treats every non-done process as enabled — a strict superset of the
@@ -157,8 +157,9 @@ def make_stepper(handlers, env, layout, *, model_seed: int = 0):
             t_recover=engine.INF)
 
         def one(p):
-            out = jax.lax.switch(st.pc[p], handlers, p,
-                                 jnp.float32(0.0), key0, st)
+            now = jnp.float32(0.0)
+            eff = jax.lax.switch(st.pc[p], handlers, p, now, key0, st)
+            out = engine.apply_effect(env, st, eff, p, now)
             return (out.window, out.pc, out.regs, out.done,
                     out.acq_count, out.writer_active, out.reader_active,
                     out.violations, out.crashed, out.in_cs,

@@ -13,7 +13,9 @@ hot word, declared writes, next pc, watch words) are captured by
 temporarily patching the `finish_instr` / `cs_enter` / `cs_exit`
 globals of each handler's defining module: the programs import those
 names from `repro.core.engine` at module scope, so rebinding the module
-attribute intercepts the call while `patched(...)` is active.
+attribute intercepts the call while `patched(...)` is active. The
+handler's returned `engine.Effect` is then applied by
+`engine.apply_effect`, the step loop's own tail.
 
 Recording is a best-effort superset/subset pair by design: reads and
 writes funneled through `jnp.where`-combined arrays lose the TraceArray
@@ -237,18 +239,16 @@ class patched:
     def __enter__(self):
         rec = self._rec
 
-        def finish(env, st, p, now, key, *, dur, hot_word, writes,
-                   next_pc, regs_row, block_a=None, block_b=None,
-                   window=None, reset_backoff=False, extra=None):
+        def finish(env, st, p, now, key, *, hot_word, writes, next_pc,
+                   regs_row, block_a=None, block_b=None, **effect):
             rec.note_finish(hot_word, writes, next_pc, block_a, block_b,
                             regs_row)
             rec.active = False        # engine internals are not program
             try:                      # address expressions
                 return _REAL_FINISH(
-                    env, st, p, now, key, dur=dur, hot_word=hot_word,
+                    env, st, p, now, key, hot_word=hot_word,
                     writes=writes, next_pc=next_pc, regs_row=regs_row,
-                    block_a=block_a, block_b=block_b, window=window,
-                    reset_backoff=reset_backoff, extra=extra)
+                    block_a=block_a, block_b=block_b, **effect)
             finally:
                 rec.active = True
 
@@ -295,9 +295,15 @@ def record_step(handlers, env, layout, canon, pc: int, p: int,
     """
     rec = Recorder()
     st = traced_state(canon, env, layout, rec)
+    p, now = np.int32(p), np.float32(0.0)
     with patched(handlers, rec):
-        handlers[pc](np.int32(p), np.float32(0.0), key, st)
+        eff = handlers[pc](p, now, key, st)
     if not rec.finished:
         raise RuntimeError(
             f"handler for pc {pc} returned without calling finish_instr")
+    rec.active = False
+    try:
+        engine.apply_effect(env, st, eff, p, now)
+    finally:
+        rec.active = True
     return rec

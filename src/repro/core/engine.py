@@ -50,8 +50,9 @@ INF = jnp.float32(3.4e38)
 # paths (`run_sim` / `run_sim_batch`) are traced through
 # `jax.experimental.checkify` with index checks plus the protocol
 # assertions below — every gather/scatter index is validated and
-# `finish_instr`'s declared effects (hot word, write set, watch words)
-# are bounds-checked and checked against the padded dead-counter slots.
+# each instruction's declared effects (hot word, write set, watch
+# words; `apply_effect`) are bounds-checked and checked against the
+# padded dead-counter slots.
 # The static counterpart is `repro.analysis.locklint`. Off by default:
 # checkify adds error plumbing through the while_loop carry and roughly
 # doubles compile time, so production sweeps never pay for it.
@@ -135,9 +136,13 @@ class SimState(NamedTuple):
     t_recover: jnp.ndarray   # float32 [] first successful reclaim time (INF = none)
 
 
-@dataclasses.dataclass(frozen=True)
+@dataclasses.dataclass(frozen=True, eq=False)
 class Env:
-    """Static (traced-constant) simulation environment shared by handlers."""
+    """Static (traced-constant) simulation environment shared by handlers.
+
+    Compared and hashed by identity, as the `StepTable` that carries it
+    is a static jit argument.
+    """
 
     P: int
     N: int
@@ -188,7 +193,7 @@ class Env:
         return jnp.sum(self.ctr_mask.astype(jnp.int32))
 
 
-# Handler signature: (env, p, now, key, st) -> SimState
+# Handler signature: (p, now, key, st) -> Effect (see `finish_instr`)
 Handler = Callable
 
 
@@ -256,28 +261,90 @@ def leases_expired(env: Env, st: SimState, now) -> jnp.ndarray:
     return st.crashed & (now >= st.crash_t + env.lease)
 
 
+# The most words one instruction declares written (REC_INHERIT and
+# REC_TAILFIX write three). `Effect.writes` is padded to it with -1.
+MAX_WRITES = 3
+
+
+class Effect(NamedTuple):
+    """What one instruction did, as its handler returns it.
+
+    `finish_instr` builds it and `apply_effect` applies it. Under `vmap`
+    the step loop's switch runs every handler, so the shared bookkeeping
+    tail runs once, after the switch, on the selected effect. The record
+    has one fixed width, so every branch returns the same shapes.
+    """
+
+    st: SimState             # the handler's state: window, CS and done accounting
+    dur: jnp.ndarray         # float32 [] duration before jitter
+    key: jnp.ndarray         # PRNG key of the jitter draw
+    hot_word: jnp.ndarray    # int32 [] word whose occupancy serializes the op (-1 = none)
+    writes: jnp.ndarray      # int32 [MAX_WRITES] words written (-1 = none)
+    next_pc: jnp.ndarray     # int32 []
+    regs_row: jnp.ndarray    # int32 [R]
+    block_a: jnp.ndarray     # int32 [] word to watch (-1 = not blocked)
+    block_b: jnp.ndarray     # int32 []
+    reset_backoff: jnp.ndarray  # bool [] success (CS entry): backoff restarts
+    stamp_attempt: jnp.ndarray  # bool [] the next attempt starts at the finish
+    reclaimed: jnp.ndarray   # bool [] a recovery repair succeeded
+    retried: jnp.ndarray     # bool [] a recovery guard failed: bounded retry
+
+
 def finish_instr(env: Env, st: SimState, p, now, key, *, dur, hot_word,
                  writes: Sequence, next_pc, regs_row,
                  block_a=None, block_b=None, window=None,
-                 reset_backoff: bool = False,
-                 extra: Callable = None) -> SimState:
-    """Common bookkeeping tail of every instruction handler.
+                 reset_backoff=False, stamp_attempt=False,
+                 reclaimed=False, retried=False) -> Effect:
+    """The effect every instruction handler returns (see `Effect`).
 
-    writes: list of word indices written (watchers get woken).
+    st: the handler's state (after `cs_enter`, `cs_exit`, a done count).
+    writes: word indices written, at most MAX_WRITES (watchers get
+    woken); an entry -1 means "no write this time".
     hot_word: word whose occupancy serializes this op (-1 = none).
     block_a/b: words to (re)watch; None = not blocked.
+    window: the window after the instruction; None = unchanged.
+    stamp_attempt: the process starts its next acquire attempt when the
+    instruction finishes (`t_attempt`).
+    reclaimed/retried: a recovery handler's repair succeeded / its guard
+    failed and it retries; counted in `reclaims` / `rec_retries`.
     """
-    dur = jnp.asarray(dur, jnp.float32)
-    jit_amt = jax.random.uniform(key, (), jnp.float32, 0.0, env.cost.jitter)
-    hot = jnp.asarray(hot_word, jnp.int32)
+    if len(writes) > MAX_WRITES:
+        raise ValueError(f"an instruction declares at most {MAX_WRITES} "
+                         f"written words, got {len(writes)}")
+    none = jnp.int32(-1)
+    words = [jnp.asarray(w, jnp.int32) for w in writes]
+    return Effect(
+        st=st if window is None else st._replace(window=window),
+        dur=jnp.asarray(dur, jnp.float32), key=key,
+        hot_word=jnp.asarray(hot_word, jnp.int32),
+        writes=jnp.stack(words + [none] * (MAX_WRITES - len(words))),
+        next_pc=jnp.asarray(next_pc, jnp.int32),
+        regs_row=jnp.asarray(regs_row, jnp.int32),
+        block_a=none if block_a is None else jnp.asarray(block_a, jnp.int32),
+        block_b=none if block_b is None else jnp.asarray(block_b, jnp.int32),
+        reset_backoff=jnp.asarray(reset_backoff, bool),
+        stamp_attempt=jnp.asarray(stamp_attempt, bool),
+        reclaimed=jnp.asarray(reclaimed, bool),
+        retried=jnp.asarray(retried, bool))
+
+
+def apply_effect(env: Env, st_before: SimState, eff: Effect, p,
+                 now) -> SimState:
+    """Common bookkeeping tail of every instruction: apply the effect
+    `eff` of process p's instruction, scheduled at `now` in state
+    `st_before`. The wake test reads the handler's `window`, `done` and
+    `crashed` (`eff.st`) against the window of `st_before`."""
+    st = eff.st
+    dur = eff.dur
+    jit_amt = jax.random.uniform(eff.key, (), jnp.float32, 0.0,
+                                 env.cost.jitter)
+    hot = eff.hot_word
     if _SANITIZE_TRACING:
         _sanitize_word(env, "hot", hot, allow_none=True)
-        for w in writes:
-            _sanitize_word(env, "write", w, allow_none=True)
-        if block_a is not None:
-            _sanitize_word(env, "block_a", block_a, allow_none=True)
-        if block_b is not None:
-            _sanitize_word(env, "block_b", block_b, allow_none=True)
+        for i in range(MAX_WRITES):
+            _sanitize_word(env, "write", eff.writes[i], allow_none=True)
+        _sanitize_word(env, "block_a", eff.block_a, allow_none=True)
+        _sanitize_word(env, "block_b", eff.block_b, allow_none=True)
         checkify.check(dur >= 0, "negative instruction duration {d}", d=dur)
     busy_at = jnp.where(hot >= 0, st.busy[jnp.maximum(hot, 0)], jnp.float32(0))
     start = jnp.maximum(now, busy_at)
@@ -286,7 +353,7 @@ def finish_instr(env: Env, st: SimState, p, now, key, *, dur, hot_word,
     busy = jnp.where(hot >= 0, busy.at[jnp.maximum(hot, 0)].set(
         start + env.cost.occupancy), busy)
 
-    window = st.window if window is None else window
+    window = st.window
     t_ready = st.t_ready
     blocked_a, blocked_b = st.blocked_a, st.blocked_b
     # The executing process always sheds its stale watch state first
@@ -296,12 +363,13 @@ def finish_instr(env: Env, st: SimState, p, now, key, *, dur, hot_word,
     # Wake watchers of written words — but only if the stored value
     # actually changed (a spinner only observes changes; a failed CAS or
     # an idempotent Put must not wake the herd). A -1 entry means "no
-    # write this time" (data-dependent write sets); it must not match
-    # the -1 in blocked_a/b, which marks a process as NOT blocked.
-    for w in writes:
-        w = jnp.asarray(w, jnp.int32)
+    # write this time" (data-dependent write sets, and the padding); it
+    # must not match the -1 in blocked_a/b, which marks a process as NOT
+    # blocked.
+    for i in range(MAX_WRITES):
+        w = eff.writes[i]
         ws = jnp.maximum(w, 0)
-        changed = (st.window[ws] != window[ws]) & (w >= 0)
+        changed = (st_before.window[ws] != window[ws]) & (w >= 0)
         # Crashed processes must never be woken: a parked (crashed
         # forever) process sits at t_ready == INF and a crash-with-
         # revive one at exactly revive_t; a wake would pull either
@@ -313,9 +381,8 @@ def finish_instr(env: Env, st: SimState, p, now, key, *, dur, hot_word,
         blocked_a = jnp.where(hit, -1, blocked_a)
         blocked_b = jnp.where(hit, -1, blocked_b)
 
-    # block_a/block_b are runtime values: -1 (or None) means "not blocked".
-    ba = jnp.asarray(-1 if block_a is None else block_a, jnp.int32)
-    bb = jnp.asarray(-1 if block_b is None else block_b, jnp.int32)
+    # block_a/block_b are runtime values: -1 means "not blocked".
+    ba, bb = eff.block_a, eff.block_b
     blocked_now = (ba >= 0) | (bb >= 0)
     blocked_a = blocked_a.at[p].set(ba)
     blocked_b = blocked_b.at[p].set(bb)
@@ -325,25 +392,31 @@ def finish_instr(env: Env, st: SimState, p, now, key, *, dur, hot_word,
     # persist across the loop's non-blocking instructions, reset only on
     # success (CS entry) — otherwise centralized locks livelock instead
     # of degrading, and we could not reproduce the paper's §5 contrasts.
-    kept = env.cost.backoff0 if reset_backoff else st.backoff[p]
+    kept = jnp.where(eff.reset_backoff, env.cost.backoff0, st.backoff[p])
     backoff = st.backoff.at[p].set(
         jnp.where(blocked_now,
                   jnp.minimum(st.backoff[p] * 2.0, env.cost.backoff_max),
                   kept))
 
-    st = st._replace(
-        window=window, pc=st.pc.at[p].set(jnp.asarray(next_pc, jnp.int32)),
-        regs=st.regs.at[p].set(regs_row), t_ready=t_ready,
+    return st._replace(
+        pc=st.pc.at[p].set(eff.next_pc),
+        regs=st.regs.at[p].set(eff.regs_row), t_ready=t_ready,
         blocked_a=blocked_a, blocked_b=blocked_b, backoff=backoff,
         busy=busy, clock=now,
         # Makespan accounting: the simulation ends when the last
         # instruction FINISHES, not when it starts — `clock` alone
         # under-reports by one instruction latency.
         t_finish=jnp.maximum(st.t_finish, finish),
-        events=st.events + 1)
-    if extra is not None:
-        st = extra(st, finish)
-    return st
+        events=st.events + 1,
+        t_attempt=st.t_attempt.at[p].set(
+            jnp.where(eff.stamp_attempt, finish, st.t_attempt[p])),
+        # A recovery handler counts a successful reclaim or a
+        # bounded-retry re-check, and stamps the first recovery's finish.
+        reclaims=st.reclaims + jnp.where(eff.reclaimed, 1, 0),
+        rec_retries=st.rec_retries + jnp.where(eff.retried, 1, 0),
+        t_recover=jnp.where(eff.reclaimed,
+                            jnp.minimum(st.t_recover, finish),
+                            st.t_recover))
 
 
 def cs_enter(env: Env, st: SimState, p, now) -> SimState:
@@ -516,24 +589,10 @@ def init_state(env: Env, layout: Layout, init_pc: np.ndarray,
         t_recover=INF)
 
 
-def recovery_extra(reclaimed, retried):
-    """`finish_instr(extra=...)` hook for protocol recovery handlers:
-    count a successful reclaim / a bounded-retry re-check and stamp the
-    first recovery completion time."""
-    def extra(s: SimState, finish):
-        return s._replace(
-            reclaims=s.reclaims + jnp.where(reclaimed, 1, 0),
-            rec_retries=s.rec_retries + jnp.where(retried, 1, 0),
-            t_recover=jnp.where(reclaimed,
-                                jnp.minimum(s.t_recover, finish),
-                                s.t_recover))
-    return extra
-
-
 def _fault_event(st: SimState, p, now) -> SimState:
     """Crash (first scheduling at/after crash_t) or revive (scheduling
-    at revive_t) of process p. Env-free on purpose: `step_loop` has no
-    env, so CS occupancy is recovered from `in_cs` (2 = writer).
+    at revive_t) of process p. Env-free: CS occupancy is recovered
+    from `in_cs` (2 = writer).
 
     A crash releases the victim's CS occupancy for *accounting* only —
     its window words keep whatever half-written protocol state they had
@@ -573,9 +632,20 @@ def _fault_event(st: SimState, p, now) -> SimState:
         clock=now, events=st.events + 1)
 
 
+class HandlerTable(tuple):
+    """A program's handlers, one per pc, and the env they were built
+    for, under which `step_loop` applies their effects."""
+
+    def __new__(cls, handlers, env: Env):
+        table = super().__new__(cls, handlers)
+        table.env = env
+        return table
+
+
 class StepTable(NamedTuple):
     """What `step_loop` runs: the handlers its switch holds, the slot in
-    it of every pc, and whether the loop carries the fault branch.
+    it of every pc, whether the loop carries the fault branch, and the
+    env the handlers were built for.
 
     `slots` is None where the switch holds every pc at its own index.
     Hashable, so it is a static jit argument: equal tables share one
@@ -585,6 +655,7 @@ class StepTable(NamedTuple):
     handlers: tuple
     slots: tuple | None
     faults: bool
+    env: Env
 
 
 def unreachable_pcs(program, env: Env, *, faults: bool) -> frozenset:
@@ -597,19 +668,22 @@ def unreachable_pcs(program, env: Env, *, faults: bool) -> frozenset:
     return meta.dead_pcs | meta.recovery_pcs
 
 
-def _trap(p, now, key, st: SimState) -> SimState:
+def _trap(p, now, key, st: SimState) -> Effect:
     """The one slot of every pruned pc. Reaching it means a declaration
     of `unreachable_pcs` is wrong: under the sanitizer a check names the
-    pc; without it the run ends with the process not done, its state
-    unchanged but for `events`, set past any `max_events`."""
+    pc; without it the run ends with the process not done and `events`
+    at int32 max, past any `max_events` (the tail adds this trip's one).
+    """
     if _SANITIZE_TRACING:
         checkify.check(jnp.bool_(False),
                        "pc {pc} was declared unreachable in this run",
                        pc=st.pc[p])
-    return st._replace(events=jnp.int32(np.iinfo(np.int32).max))
+    st = st._replace(events=jnp.int32(np.iinfo(np.int32).max - 1))
+    return finish_instr(None, st, p, now, key, dur=0.0, hot_word=-1,
+                        writes=[], next_pc=st.pc[p], regs_row=st.regs[p])
 
 
-def prune(handlers: Sequence[Callable], unreachable: frozenset, *,
+def prune(handlers: HandlerTable, unreachable: frozenset, *,
           faults: bool) -> StepTable:
     """The step table of a full handler table: its live handlers, then
     one shared trap slot that every pc of `unreachable` maps to. Counts
@@ -617,18 +691,11 @@ def prune(handlers: Sequence[Callable], unreachable: frozenset, *,
     live = [pc for pc in range(len(handlers)) if pc not in unreachable]
     spans.count("program.pruned_pcs", len(handlers) - len(live))
     if len(live) == len(handlers):
-        return StepTable(tuple(handlers), None, faults)
+        return StepTable(tuple(handlers), None, faults, handlers.env)
     slot = {pc: i for i, pc in enumerate(live)}
     slots = tuple(slot.get(pc, len(live)) for pc in range(len(handlers)))
     return StepTable(tuple(handlers[pc] for pc in live) + (_trap,), slots,
-                     faults)
-
-
-def _as_table(table) -> StepTable:
-    """A bare handler table runs every pc, with the fault branch."""
-    if isinstance(table, StepTable):
-        return table
-    return StepTable(tuple(table), None, True)
+                     faults, handlers.env)
 
 
 _TABLES = {False: {}, True: {}}
@@ -643,31 +710,33 @@ def run_table(program, env: Env, *, faults: bool) -> StepTable:
     def derive(handlers):
         if faults:
             recovery = program.meta(env).recovery_pcs
-            handlers = tuple(_scoped(h, "recovery") if pc in recovery
-                             else h for pc, h in enumerate(handlers))
+            handlers = HandlerTable(
+                (_scoped(h, "recovery") if pc in recovery else h
+                 for pc, h in enumerate(handlers)), handlers.env)
         return prune(handlers, unreachable_pcs(program, env, faults=faults),
                      faults=faults)
 
     return memoized_build(_TABLES[faults], program.build(env), derive)
 
 
-def step_loop(table, max_events: int, st: SimState, seed) -> SimState:
+def step_loop(table: StepTable, max_events: int, st: SimState,
+              seed) -> SimState:
     """Traceable simulation core: run `st` to completion under `table`.
 
-    `table` is a `StepTable`, or a bare handler table, which runs every
-    pc with the fault branch. Each trip the scheduler (named scope
-    `sched`) picks the ready process, and a switch runs its pc's handler
-    (each branch under the scope `handlers`). A table with `faults`
-    wraps the switch in a `lax.cond` against `_fault_event` (scope
-    `fault`), which crashes or revives the process instead; a
-    crash-free table has no such branch, and its loop drops the crashed
-    terms, as nothing crashes.
+    Each trip the scheduler (named scope `sched`) picks the ready
+    process, a switch runs its pc's handler (each branch under the scope
+    `handlers`) to get the instruction's `Effect`, and `apply_effect`
+    applies it once (scope `tail`). A table with `faults` wraps switch
+    and tail in a `lax.cond` against `_fault_event` (scope `fault`),
+    which crashes or revives the process instead; a crash-free table
+    has no such branch, and its loop drops the crashed terms, as
+    nothing crashes.
 
     Plain function (no jit) so callers can embed it under their own
     jit/vmap — `run_sim_batch` vmaps it over seeds, `Session.sweep`
     additionally vmaps it over environment points.
     """
-    handlers, slots, faults = _as_table(table)
+    handlers, slots, faults, env = table
     # The scope `handlers` names each branch, not the switch: under vmap
     # the switch broadcasts its operands (the env's [P, P] tables among
     # them) to every lane, which is the loop's cost, not a handler's.
@@ -710,7 +779,10 @@ def step_loop(table, max_events: int, st: SimState, seed) -> SimState:
                 return _fault_event(op[0], op[1], op[2])
 
         def instruction(op):
-            return jax.lax.switch(pc, branches, op[1], op[2], sub, op[0])
+            st, p, now = op
+            eff = jax.lax.switch(pc, branches, p, now, sub, st)
+            with jax.named_scope("tail"):
+                return apply_effect(env, st, eff, p, now)
 
         if faults:
             st = jax.lax.cond(fault, fault_event, instruction, (st, p, now))
@@ -722,13 +794,15 @@ def step_loop(table, max_events: int, st: SimState, seed) -> SimState:
     return st
 
 
-def handler_table(handlers: Sequence[Callable], pc_names) -> tuple:
-    """The handler tuple a program's `_build` returns: handler `pc` runs
-    under the named scope `pc.<pc_names[pc]>`, which names its ops in
-    the compiled program. Counts one `program.builds`."""
+def handler_table(handlers: Sequence[Callable], pc_names,
+                  env: Env) -> HandlerTable:
+    """The handler table a program's `_build(env)` returns: handler `pc`
+    runs under the named scope `pc.<pc_names[pc]>`, which names its ops
+    in the compiled program. Counts one `program.builds`."""
     spans.count("program.builds")
-    return tuple(_scoped(h, "pc." + name)
-                 for h, name in zip(handlers, pc_names, strict=True))
+    return HandlerTable((_scoped(h, "pc." + name)
+                         for h, name in zip(handlers, pc_names, strict=True)),
+                        env)
 
 
 def _scoped(handler, scope: str):
@@ -754,21 +828,6 @@ def _checked_run(table, max_events: int):
         errors=_CHECK_ERRORS))
 
 
-@functools.lru_cache(maxsize=MEMO_MAX_ENTRIES)
-def _checked_run_batch(table, max_events: int):
-    # checkify cannot wrap a batched while-loop, so the transform order
-    # is vmap-of-checkify: each seed's run carries its own error slot
-    # and `.throw()` on the batched error reports the first failure.
-    checked = checkify.checkify(
-        lambda st, s: step_loop(table, max_events, st, s),
-        errors=_CHECK_ERRORS)
-
-    def batched(st, seeds):
-        err, final = jax.vmap(lambda s: checked(st, s))(seeds)
-        return err, jax.vmap(summarize)(final)
-    return jax.jit(batched)
-
-
 def _call_checked(fn, *args):
     """Invoke a checkified variant with the sanitizer assertions traced
     in, and raise its first pending error (if any)."""
@@ -781,6 +840,16 @@ def _call_checked(fn, *args):
         _SANITIZE_TRACING = prev
     err.throw()
     return out
+
+
+def _checked_lanes(table, max_events: int, states, seeds) -> Metrics:
+    """A batch under the sanitizer: lane s is a checked single run of
+    `seeds[s]` from `states[s]`, as checkify cannot wrap a batched
+    while-loop. Metrics leaves gain a leading [S] axis."""
+    checked = _checked_run(table, max_events)
+    runs = [summarize(_call_checked(checked, st, s))
+            for st, s in zip(states, seeds)]
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *runs)
 
 
 def _run(table, max_events: int, st: SimState, seed) -> SimState:
@@ -845,8 +914,8 @@ def _run_batch_jit(table, max_events: int, st: SimState,
 def _run_batch(table, max_events: int, st: SimState,
                seeds: jnp.ndarray) -> Metrics:
     if checks_enabled():
-        return _call_checked(_checked_run_batch(table, max_events),
-                             st, seeds)
+        return _checked_lanes(table, max_events,
+                              [st] * seeds.shape[0], seeds)
     spans.note_dispatch(_run_batch_jit, (table, max_events), (st, seeds))
     return _run_batch_jit(table, max_events, st, seeds)
 
@@ -871,13 +940,12 @@ def run_lanes(table, max_events: int, st: SimState, seeds: jnp.ndarray,
     seed `seeds[s]` under the plan `plan.crash_t[s]`, `plan.revive_t[s]`
     (leaves [S, P]) from `st`, whose own plan is replaced. Metrics
     leaves gain a leading [S] axis. Under the sanitizer each lane is a
-    checked single run: checkify cannot wrap a batched while-loop."""
+    checked single run (`_checked_lanes`)."""
     if checks_enabled():
-        checked = _checked_run(table, max_events)
-        runs = [summarize(_call_checked(
-            checked, st._replace(crash_t=c, revive_t=r), s))
-            for s, c, r in zip(seeds, plan.crash_t, plan.revive_t)]
-        return jax.tree.map(lambda *xs: jnp.stack(xs), *runs)
+        return _checked_lanes(
+            table, max_events,
+            [st._replace(crash_t=c, revive_t=r)
+             for c, r in zip(plan.crash_t, plan.revive_t)], seeds)
     args = (st, seeds, plan.crash_t, plan.revive_t)
     spans.note_dispatch(_run_lanes_jit, (table, max_events), args)
     return _run_lanes_jit(table, max_events, *args)

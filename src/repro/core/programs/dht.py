@@ -133,12 +133,10 @@ class FompiADHT:
             st = st._replace(acq_count=st.acq_count.at[p].set(cnt),
                              done=st.done.at[p].set(cnt >= env.target_acq))
 
-            def extra(s, finish):
-                return s._replace(t_attempt=s.t_attempt.at[p].set(finish))
-
             return finish_instr(env, st, p, now, key,
                                 dur=think_duration(env, key), hot_word=-1,
                                 writes=[], next_pc=A_OP,
-                                regs_row=st.regs[p], extra=extra)
+                                regs_row=st.regs[p], stamp_attempt=True)
 
-        return handler_table((a_op, a_overflow, a_done, a_chain), PC_NAMES)
+        return handler_table((a_op, a_overflow, a_done, a_chain), PC_NAMES,
+                             env)

@@ -25,8 +25,7 @@ import jax.numpy as jnp
 
 from repro.core.engine import (Env, SimState, cs_duration, cs_enter,
                                cs_exit, finish_instr, handler_table,
-                               leases_expired, memoized_build,
-                               recovery_extra, think_duration)
+                               leases_expired, memoized_build, think_duration)
 from repro.core.programs.meta import SEG_SCRATCH, ProgramMeta
 
 _NOOP = jnp.int32(-1)
@@ -133,13 +132,10 @@ class FompiSpin:
             st = st._replace(acq_count=st.acq_count.at[p].set(cnt),
                              done=st.done.at[p].set(cnt >= env.target_acq))
 
-            def extra(s, finish):
-                return s._replace(t_attempt=s.t_attempt.at[p].set(finish))
-
             return finish_instr(env, st, p, now, key,
                                 dur=think_duration(env, key), hot_word=-1,
                                 writes=[], next_pc=S_TRY,
-                                regs_row=st.regs[p], extra=extra)
+                                regs_row=st.regs[p], stamp_attempt=True)
 
         def s_rec(p, now, key, st: SimState):
             # Atomic re-validate + reclaim: CAS(dead-owner-id -> p+1).
@@ -154,10 +150,10 @@ class FompiSpin:
                                 writes=[LW],
                                 next_pc=jnp.where(dead, S_CS, S_TRY),
                                 regs_row=st.regs[p], window=win,
-                                extra=recovery_extra(dead, ~dead))
+                                reclaimed=dead, retried=~dead)
 
         return handler_table((s_try, s_cs, s_rel, s_done, s_rec),
-                             SPIN_PC_NAMES)
+                             SPIN_PC_NAMES, env)
 
 
 class FompiRW:
@@ -266,13 +262,10 @@ class FompiRW:
             st = st._replace(acq_count=st.acq_count.at[p].set(cnt),
                              done=st.done.at[p].set(cnt >= env.target_acq))
 
-            def extra(s, finish):
-                return s._replace(t_attempt=s.t_attempt.at[p].set(finish))
-
             return finish_instr(env, st, p, now, key,
                                 dur=think_duration(env, key), hot_word=-1,
                                 writes=[], next_pc=W_TRY,
-                                regs_row=st.regs[p], extra=extra)
+                                regs_row=st.regs[p], stamp_attempt=True)
 
         def r_inc(p, now, key, st: SimState):
             win = st.window.at[RC].add(1)
@@ -319,13 +312,10 @@ class FompiRW:
             st = st._replace(acq_count=st.acq_count.at[p].set(cnt),
                              done=st.done.at[p].set(cnt >= env.target_acq))
 
-            def extra(s, finish):
-                return s._replace(t_attempt=s.t_attempt.at[p].set(finish))
-
             return finish_instr(env, st, p, now, key,
                                 dur=think_duration(env, key), hot_word=-1,
                                 writes=[], next_pc=R_INC,
-                                regs_row=st.regs[p], extra=extra)
+                                regs_row=st.regs[p], stamp_attempt=True)
 
         def w_rec(p, now, key, st: SimState):
             # Atomic re-validate + steal the dead writer's flag.
@@ -337,7 +327,7 @@ class FompiRW:
                                 writes=[WF],
                                 next_pc=jnp.where(dead, W_WAITR, W_TRY),
                                 regs_row=st.regs[p], window=win,
-                                extra=recovery_extra(dead, ~dead))
+                                reclaimed=dead, retried=~dead)
 
         def r_rec(p, now, key, st: SimState):
             # Reader clears a dead writer's flag; its own arrival is
@@ -350,7 +340,7 @@ class FompiRW:
                                 dur=env.lat_atomic(p, WF), hot_word=WF,
                                 writes=[WF], next_pc=R_CHECK,
                                 regs_row=st.regs[p], window=win,
-                                extra=recovery_extra(dead, ~dead))
+                                reclaimed=dead, retried=~dead)
 
         def w_drain(p, now, key, st: SimState):
             # Atomic re-validate + zero the reader counter: every
@@ -367,8 +357,9 @@ class FompiRW:
                                 next_pc=jnp.where(stale | drained,
                                                   W_CS, W_WAITR),
                                 regs_row=st.regs[p], window=win,
-                                extra=recovery_extra(stale, ~(stale | drained)))
+                                reclaimed=stale,
+                                retried=~(stale | drained))
 
         return handler_table((w_try, w_waitr, w_cs, w_rel, w_done,
                               r_inc, r_check, r_undo, r_cs, r_rel, r_done,
-                              w_rec, r_rec, w_drain), RW_PC_NAMES)
+                              w_rec, r_rec, w_drain), RW_PC_NAMES, env)

@@ -623,13 +623,10 @@ class HierProgram:
                              done=st.done.at[p].set(finished))
             nxt = WA_PREP
 
-            def extra(s, finish):
-                return s._replace(t_attempt=s.t_attempt.at[p].set(finish))
-
             return finish_instr(env, st, p, now, key,
                                 dur=think_duration(env, key), hot_word=-1,
                                 writes=[], next_pc=nxt, regs_row=r,
-                                extra=extra)
+                                stamp_attempt=True)
 
         # ---- reader instructions (Listings 9 / 10) -------------------
         def r_barrier(p, now, key, st: SimState):
@@ -770,13 +767,10 @@ class HierProgram:
             st = st._replace(acq_count=st.acq_count.at[p].set(cnt),
                              done=st.done.at[p].set(finished))
 
-            def extra(s, finish):
-                return s._replace(t_attempt=s.t_attempt.at[p].set(finish))
-
             return finish_instr(env, st, p, now, key,
                                 dur=think_duration(env, key), hot_word=-1,
                                 writes=[], next_pc=R_BARRIER, regs_row=r,
-                                extra=extra)
+                                stamp_attempt=True)
 
         # ---- crash-recovery instructions ----------------------------
         def rec_inherit(p, now, key, st: SimState):
@@ -951,8 +945,7 @@ class HierProgram:
                 window=win,
                 block_a=jnp.where(resolved, _NOOP, pw),
                 block_b=jnp.where(resolved, _NOOP, my_w),
-                extra=engine.recovery_extra(resolved & ~own_granted,
-                                            ~resolved))
+                reclaimed=resolved & ~own_granted, retried=~resolved)
 
         def rec_tailfix(p, now, key, st: SimState):
             """Pass over (or unhook) a dead successor during release
@@ -1029,8 +1022,7 @@ class HierProgram:
                                                   nw(lvl,
                                                      jnp.maximum(e_d, 0)),
                                                   _NOOP),
-                                extra=engine.recovery_extra(repaired,
-                                                            ~repaired))
+                                reclaimed=repaired, retried=~repaired)
 
         def rec_drain(p, now, key, st: SimState):
             """Zero dead readers' unmatched arrivals on counter K so
@@ -1054,8 +1046,7 @@ class HierProgram:
                                 + env.lat_atomic(p, wa),
                                 hot_word=wa, writes=[wa], next_pc=nxt,
                                 regs_row=r, window=win,
-                                extra=engine.recovery_extra(stale,
-                                                            ~fixed))
+                                reclaimed=stale, retried=~fixed)
 
         def r_unbar(p, now, key, st: SimState):
             """A barred reader clears a counter wedged by the dead:
@@ -1079,7 +1070,7 @@ class HierProgram:
                                 hot_word=wa, writes=[wa, wd],
                                 next_pc=R_BARRIER, regs_row=r,
                                 window=win,
-                                extra=engine.recovery_extra(ok, ~ok))
+                                reclaimed=ok, retried=~ok)
 
         def trap(p, now, key, st: SimState):
             # Self-loop: pc 7 is unused, and a self-looping trap shows
@@ -1123,7 +1114,7 @@ class HierProgram:
         handlers[REC_TAILFIX] = rec_tailfix
         handlers[REC_DRAIN] = rec_drain
         handlers[R_UNBAR] = r_unbar
-        return engine.handler_table(handlers, PC_NAMES)
+        return engine.handler_table(handlers, PC_NAMES, env)
 
 
 def rma_rw() -> HierProgram:

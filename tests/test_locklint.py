@@ -194,17 +194,17 @@ def test_runtime_checks_trap_dead_counter_write():
                             regs_row=st.regs[p], window=win)
 
     def halt(p, now, key, st):
+        st = st._replace(done=st.done.at[p].set(True))
         return finish_instr(env, st, p, now, key, dur=0.0, hot_word=-1,
-                            writes=[], next_pc=1, regs_row=st.regs[p],
-                            extra=lambda s, f: s._replace(
-                                done=s.done.at[p].set(True)))
+                            writes=[], next_pc=1, regs_row=st.regs[p])
 
+    table = engine.StepTable((bad, halt), None, True, env)
     st0 = engine.init_state(env, lay, np.zeros(2, np.int32), 1)
     with engine.runtime_checks(True):
         with pytest.raises(Exception, match="dead counter"):
-            engine._run((bad, halt), 1000, st0, 0)
+            engine._run(table, 1000, st0, 0)
     # The same run is silent without the sanitizer.
-    assert int(engine._run((bad, halt), 1000, st0, 0).events) > 0
+    assert int(engine._run(table, 1000, st0, 0).events) > 0
 
 
 # --------------------------------------------- tuner safety columns
